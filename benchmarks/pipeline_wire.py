@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.dryrun import collective_bytes
+from repro.launch.mesh import make_mesh
 
 
 def payload_bytes(scheme: str, feat_shape, k_frac: float):
@@ -146,7 +147,7 @@ def measure_schedules(*, stages=4, batch=16, seq=32, d_model=64, d_ff=128,
     from repro.transport.schedules import get_schedule
     n_dev = jax.device_count()
     assert n_dev >= stages, (n_dev, stages)
-    mesh = jax.make_mesh((stages,), ("stage",))
+    mesh = make_mesh((stages,), ("stage",))
     key = jax.random.PRNGKey(0)
 
     def stage_fn(p, h):
@@ -216,7 +217,7 @@ def measure(schemes=("none", "q8", "q4", "topk", "topk_reuse"), *, stages=4,
     from repro.transport.pipeline import pipeline_apply
     n_dev = jax.device_count()
     assert n_dev >= stages, (n_dev, stages)
-    mesh = jax.make_mesh((stages,), ("stage",))
+    mesh = make_mesh((stages,), ("stage",))
 
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
@@ -589,7 +590,7 @@ def measure_telemetry(schemes=("none", "q8", "q4", "topk", "topk_reuse"),
     # -- enabled-tracing overhead on a real jitted pipeline step ------------
     from repro.transport.pipeline import pipeline_apply
     import time
-    mesh = jax.make_mesh((stages,), ("stage",))
+    mesh = make_mesh((stages,), ("stage",))
     params = {"w": jnp.full((stages, 1, 1), 1.0, jnp.bfloat16)}
 
     def run(p, xx):

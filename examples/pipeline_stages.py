@@ -21,10 +21,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.launch.mesh import make_mesh
 from repro.transport import (get_schedule, pack_payload, pipeline_forward,
                              wire_bytes)
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 B, D = 8, 256
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (B, D), jnp.float32)

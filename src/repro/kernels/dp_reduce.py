@@ -106,22 +106,23 @@ def extract_meta(slots: jnp.ndarray, plans: Sequence[LeafPlan]):
 
 def _decode_sum_kernel(slots_ref, meta_ref, *o_refs,
                        plans: Sequence[LeafPlan], dp: int):
+    """One output per q8 leaf; two (even and odd nibble planes) per q4
+    leaf — Mosaic has no lane interleave, so the planes are interleaved
+    once, after the fold, in XLA.  uint8 widens through int32 (Mosaic has
+    no uint8->float cast)."""
+    outs = iter(o_refs)
     for li, p in enumerate(plans):
-        acc = None
+        accs = None
         for s in range(dp):                  # static rank-ordered fold
-            seg = slots_ref[s:s + 1, p.off:p.off + p.nbytes]
+            seg = slots_ref[s:s + 1, p.off:p.off + p.nbytes].astype(
+                jnp.int32)
             mn = meta_ref[s, 2 * li]
             sc = meta_ref[s, 2 * li + 1]
-            if p.kind == "q8":
-                codes = seg.astype(jnp.float32)
-            else:
-                even = (seg & 0xF).astype(jnp.float32)
-                odd = (seg >> 4).astype(jnp.float32)
-                codes = jnp.stack([even, odd],
-                                  axis=-1).reshape(1, -1)[:, :p.n]
-            d = codes * sc + mn
-            acc = d if acc is None else acc + d
-        o_refs[li][...] = acc
+            planes = ((seg,) if p.kind == "q8" else (seg & 0xF, seg >> 4))
+            ds = [c.astype(jnp.float32) * sc + mn for c in planes]
+            accs = ds if accs is None else [a + d for a, d in zip(accs, ds)]
+        for a in accs:
+            next(outs)[...] = a
 
 
 def decode_fits(plans: Sequence[LeafPlan], dp: int,
@@ -145,10 +146,18 @@ def decode_sum_fused(slots: jnp.ndarray, plans: Sequence[LeafPlan],
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     meta = extract_meta(slots, plans)
-    out = pl.pallas_call(
+    out = iter(pl.pallas_call(
         functools.partial(_decode_sum_kernel, plans=tuple(plans), dp=dp),
-        out_shape=[jax.ShapeDtypeStruct((1, p.n), jnp.float32)
-                   for p in plans],
+        out_shape=[jax.ShapeDtypeStruct((1, p.nbytes), jnp.float32)
+                   for p in plans for _ in range(1 if p.kind == "q8" else 2)],
         interpret=interpret,
-    )(slots, meta)
-    return list(out)
+    )(slots, meta))
+    dense = []
+    for p in plans:
+        if p.kind == "q8":
+            dense.append(next(out))
+        else:
+            even, odd = next(out), next(out)
+            dense.append(jnp.stack([even, odd], axis=-1)
+                         .reshape(1, -1)[:, :p.n])
+    return dense

@@ -25,10 +25,14 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# The whole hop buffer is resident twice (segments + output); stay well
-# under the ~16 MB of VMEM.
+# The whole hop buffer is resident twice (segments + output).  A (1, n)
+# uint8 row sits in VMEM padded to 4 sublanes, so the kernel needs about
+# 8x the buffer's bytes: 32 MiB at the cap, over the 16 MiB default
+# scoped limit of a v5e (which has 128 MiB of VMEM).
 FRAME_MAX_BYTES = 4 * 1024 * 1024
+FRAME_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _frame_kernel(*refs, sizes: Sequence[int]):
@@ -61,6 +65,8 @@ def frame_parts(parts: List[jnp.ndarray], *,
     buf = pl.pallas_call(
         functools.partial(_frame_kernel, sizes=sizes),
         out_shape=jax.ShapeDtypeStruct((1, total), jnp.uint8),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FRAME_VMEM_LIMIT),
         interpret=interpret,
     )(*[p.reshape(1, -1) for p in parts])
     return buf.reshape(-1)
@@ -84,6 +90,8 @@ def unframe_parts(buf: jnp.ndarray, sizes: Sequence[int], *,
     segs = pl.pallas_call(
         functools.partial(_unframe_kernel, sizes=live),
         out_shape=[jax.ShapeDtypeStruct((1, nb), jnp.uint8) for nb in live],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FRAME_VMEM_LIMIT),
         interpret=interpret,
     )(buf.reshape(1, -1))
     segs = iter(segs)

@@ -33,16 +33,31 @@ def _qdq_kernel(x_ref, o_ref, *, levels: int):
 
 
 def _quantize_kernel(x_ref, codes_ref, meta_ref, *, levels: int):
-    """Wire-format variant: uint8 codes + per-tile (min, scale) pair."""
+    """Wire-format variant: uint8 codes + per-tile (min, scale) pair.
+
+    The (gm, 2 gn) meta array stays resident in VMEM for the whole grid
+    (a (1, 2) block per tile is not a legal Mosaic block): each tile
+    writes its two lanes through a mask.  Mosaic has no float->uint8
+    cast, so the codes go through int32."""
+    i, j = pl.program_id(0), pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)
     xmin = jnp.min(x)
     xmax = jnp.max(x)
     span = xmax - xmin
     scale = jnp.where(span > 0, span / levels, 1.0)
     codes = jnp.clip(jnp.round((x - xmin) / scale), 0.0, float(levels))
-    codes_ref[...] = codes.astype(jnp.uint8)
-    meta_ref[0, 0] = xmin
-    meta_ref[0, 1] = scale
+    codes_ref[...] = codes.astype(jnp.int32).astype(jnp.uint8)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        meta_ref[...] = jnp.zeros(meta_ref.shape, meta_ref.dtype)
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, meta_ref.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, meta_ref.shape, 1)
+    here = rows == i
+    meta_ref[...] = jnp.where(
+        here & (cols == 2 * j), xmin,
+        jnp.where(here & (cols == 2 * j + 1), scale, meta_ref[...]))
 
 
 def quant_dequant(x: jnp.ndarray, bits: int, *, block=(256, 256),
@@ -84,7 +99,7 @@ def quantize_wire(x: jnp.ndarray, bits: int, *, block=(256, 256),
         grid=(gm, gn),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=(pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, 2), lambda i, j: (i, j))),
+                   pl.BlockSpec((gm, 2 * gn), lambda i, j: (0, 0))),
         interpret=interpret,
     )(x)
     return codes, meta

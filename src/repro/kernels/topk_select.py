@@ -8,7 +8,12 @@ sort with an EXACT threshold search: |x| is bitcast to int32 (the IEEE
 ordering trick — non-negative floats compare identically to their bit
 patterns), and 31 fixed bisection steps over the bit space find the
 k-th-largest magnitude's exact bit pattern with nothing but vector
-compares and per-row sum reductions, the whole row resident in VMEM.
+compares and per-row sum reductions.  A boundary row (seq x d_model
+floats) does not fit VMEM, so the grid is (row blocks, bit, column
+blocks): each bisection step streams the row through in (bm, bn) blocks,
+sums the per-row count, and settles its bit after the last block.
+Widths that are not a lane multiple are zero-padded: a zero magnitude
+never counts, since every candidate bit pattern is >= 1.
 Unlike the approximate magnitude bisection in kernels/topk_mask.py, the
 bit-space search terminates at the EXACT k-th value, so the selected set
 matches ``lax.top_k`` entry-for-entry.
@@ -30,19 +35,34 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import full_row_block
+from repro.kernels.tiling import column_tiling, padded_width
 
 
-def _threshold_kernel(x_ref, t_ref, *, k: int):
-    mag = jnp.abs(x_ref[...].astype(jnp.float32))       # (bm, n)
+_BITS = 31                         # magnitude bits below the sign
+
+
+def _threshold_kernel(x_ref, t_ref, cnt_ref, *, k: int):
+    b, c = pl.program_id(1), pl.program_id(2)
+    last_c = pl.num_programs(2) - 1
+
+    @pl.when((b == 0) & (c == 0))
+    def _():
+        t_ref[...] = jnp.zeros(t_ref.shape, t_ref.dtype)
+
+    @pl.when(c == 0)
+    def _():
+        cnt_ref[...] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
+
+    t = t_ref[...]                                      # (bm, 1) int32
+    cand = t | jnp.left_shift(jnp.int32(1), _BITS - 1 - b)
+    mag = jnp.abs(x_ref[...].astype(jnp.float32))       # (bm, bn)
     bits = jax.lax.bitcast_convert_type(mag, jnp.int32)
-    t = jnp.zeros((bits.shape[0], 1), jnp.int32)
-    for b in range(30, -1, -1):                         # static unroll
-        cand = t | (1 << b)
-        cnt = jnp.sum((bits >= cand).astype(jnp.int32), axis=1,
-                      keepdims=True)
-        t = jnp.where(cnt >= k, cand, t)
-    t_ref[...] = jax.lax.bitcast_convert_type(t, jnp.float32)
+    cnt_ref[...] += jnp.sum((bits >= cand).astype(jnp.int32), axis=1,
+                            keepdims=True)
+
+    @pl.when(c == last_c)
+    def _():
+        t_ref[...] = jnp.where(cnt_ref[...] >= k, cand, t)
 
 
 def topk_threshold(flat: jnp.ndarray, k: int, *,
@@ -52,17 +72,24 @@ def topk_threshold(flat: jnp.ndarray, k: int, *,
     assert flat.ndim == 2, flat.shape
     m, n = flat.shape
     assert 1 <= k <= n, (k, n)
-    bm = full_row_block(m, n)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return pl.pallas_call(
+    npad = padded_width(n, 128)
+    if npad != n:
+        flat = jnp.pad(flat, ((0, 0), (0, npad - n)))
+    bm, bn = column_tiling(m, npad, max_lanes=32768,
+                           bytes_per_elem=flat.dtype.itemsize)
+    t, _ = pl.pallas_call(
         functools.partial(_threshold_kernel, k=k),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+        out_shape=(jax.ShapeDtypeStruct((m, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((m, 1), jnp.int32)),
+        grid=(m // bm, _BITS, npad // bn),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, b, c: (i, c))],
+        out_specs=(pl.BlockSpec((bm, 1), lambda i, b, c: (i, 0)),
+                   pl.BlockSpec((bm, 1), lambda i, b, c: (i, 0))),
         interpret=interpret,
     )(flat)
+    return jax.lax.bitcast_convert_type(t, jnp.float32)
 
 
 def topk_select_wire(flat: jnp.ndarray, k: int, *,

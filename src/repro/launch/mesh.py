@@ -7,12 +7,25 @@ device state.  Production target: TPU v5e pods — 16x16 = 256 chips per pod,
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    Since JAX 0.9 ``jax.make_mesh`` types axes ``Explicit`` by default,
+    under which ``dynamic_slice`` of a stage-sharded pipeline output
+    raises ``ShardingTypeError``.  Every mesh of this repo is built here,
+    so the partitioner (not sharding-in-types) places the unsharded ops.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "tensor") if multi_pod else ("data", "tensor")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh():
@@ -21,7 +34,7 @@ def make_local_mesh():
     Uses the canonical ``(data, tensor)`` names (core/parallel.py);
     sharding/specs.py accepts the historical "model" name as an alias.
     """
-    return jax.make_mesh((1, 1), ("data", "tensor"))
+    return make_mesh((1, 1), ("data", "tensor"))
 
 
 def make_data_mesh(dp: int, *, data_axis: str = "data"):
@@ -34,7 +47,7 @@ def make_data_mesh(dp: int, *, data_axis: str = "data"):
             f"data-parallel mesh needs >= {dp} devices, have "
             f"{jax.device_count()} — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={dp} before jax init")
-    return jax.make_mesh((dp,), (data_axis,))
+    return make_mesh((dp,), (data_axis,))
 
 
 def make_dp_pipeline_mesh(dp: int, stages: int, *, data_axis: str = "data",
@@ -52,7 +65,7 @@ def make_dp_pipeline_mesh(dp: int, stages: int, *, data_axis: str = "data",
             f"2D DPxPP mesh needs >= {need} devices (dp={dp} x "
             f"stages={stages}), have {jax.device_count()} — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before jax init")
-    return jax.make_mesh((dp, stages), (data_axis, stage_axis))
+    return make_mesh((dp, stages), (data_axis, stage_axis))
 
 
 def make_tensor_mesh(tp: int, *, tensor_axis: str = "tensor"):
@@ -66,7 +79,7 @@ def make_tensor_mesh(tp: int, *, tensor_axis: str = "tensor"):
             f"tensor-parallel mesh needs >= {tp} devices, have "
             f"{jax.device_count()} — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={tp} before jax init")
-    return jax.make_mesh((tp,), (tensor_axis,))
+    return make_mesh((tp,), (tensor_axis,))
 
 
 def make_3d_mesh(dp: int, stages: int, tp: int, *, data_axis: str = "data",
@@ -89,10 +102,5 @@ def make_3d_mesh(dp: int, stages: int, tp: int, *, data_axis: str = "data",
             f"3D mesh needs >= {need} devices (dp={dp} x stages={stages} "
             f"x tp={tp}), have {jax.device_count()} — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before jax init")
-    return jax.make_mesh((dp, stages, tp), (data_axis, stage_axis, tensor_axis))
+    return make_mesh((dp, stages, tp), (data_axis, stage_axis, tensor_axis))
 
-
-# Hardware constants for §Roofline (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link

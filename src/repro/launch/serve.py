@@ -39,6 +39,7 @@ import jax
 
 from repro.checkpoint import io as ckpt_io
 from repro.configs.registry import ARCHS, get
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import POLICIES
 from repro.models import encdec, transformer
 from repro.serve.engine import (ContinuousEngine, Request, ServeEngine,
@@ -138,6 +139,7 @@ def main(argv=None) -> int:
                          "counters every N ticks when tracing is on "
                          "(default 1)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracing = bool(args.trace or args.perfetto)
     if tracing:

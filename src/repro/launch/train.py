@@ -42,6 +42,7 @@ from repro.configs.registry import ARCHS, get
 from repro.obs import trace as obs_trace
 from repro.core.boundary import init_boundary_state
 from repro.core.parallel import spec_from_cli
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.policy import (CompressionPolicy, NO_POLICY, PolicyRules,
                                aqsgd_policy, ef_policy, parse_policy_rules,
                                quant_policy, resolve_policy, topk_policy)
@@ -111,6 +112,36 @@ def make_batch(cfg, tokens):
         b["enc_embeds"] = jnp.zeros((n, cfg.enc_seq, cfg.d_model),
                                     jnp.bfloat16)
     return b
+
+
+def adamw_config(lr: float, steps: int) -> OptimizerConfig:
+    """The trainer's optimizer: AdamW with a cosine decay over ``steps``."""
+    return OptimizerConfig(kind="adamw", lr=lr, weight_decay=0.01,
+                           schedule="cosine", t_max=steps, grad_clip=1.0)
+
+
+def init_bstates(cfg, policy, transport: str, *, seq: int, batch: int,
+                 num_samples: int = 4096, microbatches=None,
+                 virtual_stages: int = 1, dp: int = 1):
+    """The boundary feedback state the train step threads, for the
+    EFFECTIVE ``(policy, transport)`` that ``_resolve_parallel`` returns."""
+    if transport == "pipeline":
+        from repro.train.loop import _pipeline_bstates
+        return _pipeline_bstates(
+            policy, (seq, cfg.d_model), batch=batch,
+            microbatches=microbatches, num_samples=num_samples,
+            dtype=jnp.bfloat16, virtual_stages=virtual_stages, dp=dp)
+    # boundaries that actually exist in the stack: segment_bounds caps
+    # the stage count at the group count (a 2-group smoke model under a
+    # 4-stage policy has 1 cut, not 3) — and the train step returns
+    # bstates in that effective structure, which --resume restores into
+    from repro.models.transformer import segment_bounds
+    n_units = cfg.num_layers if cfg.enc_dec else cfg.num_groups
+    eff = max(0, len(segment_bounds(n_units, policy.num_stages)) - 1)
+    return [init_boundary_state(policy.at(i), (seq, cfg.d_model),
+                                batch=batch, num_samples=num_samples,
+                                dtype=jnp.bfloat16)
+            for i in range(eff)]
 
 
 def main(argv=None) -> int:
@@ -237,6 +268,7 @@ def main(argv=None) -> int:
                          "feedback-buffer norms every N steps (obs/"
                          "quality.py; 0 = off; implies tracing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracing = bool(args.trace or args.perfetto or args.metrics)
     if tracing:
@@ -342,31 +374,14 @@ def main(argv=None) -> int:
           f"{'' if args.feedback == 'none' else '+' + args.feedback} "
           f"devices={jax.device_count()}", flush=True)
 
-    opt = OptimizerConfig(kind="adamw", lr=args.lr, weight_decay=0.01,
-                          schedule="cosine", t_max=args.steps, grad_clip=1.0)
+    opt = adamw_config(args.lr, args.steps)
     params = (encdec if cfg.enc_dec else transformer).init_params(
         jax.random.PRNGKey(args.seed), cfg)
     opt_state = init_opt_state(opt, params)
-    if transport_eff == "pipeline":
-        from repro.train.loop import _pipeline_bstates
-        bstates = _pipeline_bstates(
-            policy_eff, (seq, cfg.d_model), batch=args.batch,
-            microbatches=pipeline_mb,
-            num_samples=args.num_samples, dtype=jnp.bfloat16,
-            virtual_stages=virtual_stages, dp=dp_n)
-    else:
-        # boundaries that actually exist in the stack: segment_bounds caps
-        # the stage count at the group count (a 2-group smoke model under a
-        # 4-stage policy has 1 cut, not 3) — and the train step returns
-        # bstates in that effective structure, which --resume restores into
-        from repro.models.transformer import segment_bounds
-        n_units = cfg.num_layers if cfg.enc_dec else cfg.num_groups
-        eff = max(0, len(segment_bounds(n_units, policy_eff.num_stages)) - 1)
-        bstates = [init_boundary_state(policy_eff.at(i), (seq, cfg.d_model),
-                                       batch=args.batch,
-                                       num_samples=args.num_samples,
-                                       dtype=jnp.bfloat16)
-                   for i in range(eff)]
+    bstates = init_bstates(cfg, policy_eff, transport_eff, seq=seq,
+                           batch=args.batch, num_samples=args.num_samples,
+                           microbatches=pipeline_mb,
+                           virtual_stages=virtual_stages, dp=dp_n)
     if transport_eff == "pipeline":
         from repro.transport.schedules import get_schedule
         sched = get_schedule(args.schedule, virtual_stages)

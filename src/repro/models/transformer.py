@@ -178,10 +178,11 @@ def forward_train(params, batch, cfg: ModelConfig,
     return _lm_logits(params, x, cfg), aux, new_fw
 
 
-def stage_stack_fn(cfg: ModelConfig):
+def stage_stack_fn(cfg: ModelConfig, remat: bool = True):
     """``stage_fn(gp_stack, x) -> x`` applying a stacked slice of layer
     groups — the per-stage body for the REAL pipeline transport
-    (transport/pipeline.py).  MoE aux losses are dropped on this path."""
+    (transport/pipeline.py), with ``jax.checkpoint`` per group like
+    :func:`forward_hidden`.  MoE aux losses are dropped on this path."""
     kinds = cfg.layer_kinds()
 
     def stage_fn(gp_stack, x):
@@ -189,6 +190,8 @@ def stage_stack_fn(cfg: ModelConfig):
             for i, kind in enumerate(kinds):
                 x, _ = B.block_train(gp[f"b{i}"], x, cfg, kind)
             return x, None
+        if remat:
+            scan_fn = jax.checkpoint(scan_fn)
         x, _ = jax.lax.scan(scan_fn, x, gp_stack, unroll=scan_unroll())
         return x
 
@@ -239,13 +242,14 @@ def tp_sites(cfg: ModelConfig, groups: Optional[int] = None) -> int:
     return 2 * len(cfg.layer_kinds()) * g
 
 
-def tp_stage_stack_fn(cfg: ModelConfig, tpc):
+def tp_stage_stack_fn(cfg: ModelConfig, tpc, remat: bool = True):
     """``stage_fn(gp_stack, x, resid, mirror) -> (x, resid, mirror)`` —
-    the tensor-parallel twin of :func:`stage_stack_fn`, run INSIDE the
-    tensor ``shard_map`` (transport.tp_collectives.tp_apply or the 3D
-    pipeline): ``x`` is the sequence-sharded residual, ``gp_stack`` the
-    tp-local weight shards, and ``resid``/``mirror`` the site-stacked
-    feedback buffers (or size-0 placeholders for feedback "none")."""
+    the tensor-parallel twin of :func:`stage_stack_fn` (``remat`` alike),
+    run INSIDE the tensor ``shard_map`` (transport.tp_collectives.tp_apply
+    or the 3D pipeline): ``x`` is the sequence-sharded residual,
+    ``gp_stack`` the tp-local weight shards, and ``resid``/``mirror`` the
+    site-stacked feedback buffers (or size-0 placeholders for feedback
+    "none")."""
     kinds = cfg.layer_kinds()
     for kind in kinds:
         if kind not in B.TP_BLOCK_KINDS:
@@ -262,6 +266,8 @@ def tp_stage_stack_fn(cfg: ModelConfig, tpc):
                     x, _ = B.attn_block_train_tp(gp[f"b{i}"], x, cfg, kind,
                                                  tpc)
                 return x, None
+            if remat:
+                scan_fn = jax.checkpoint(scan_fn)
             x, _ = jax.lax.scan(scan_fn, x, gp_stack, unroll=scan_unroll())
             return x, resid, mirror
 
@@ -279,6 +285,8 @@ def tp_stage_stack_fn(cfg: ModelConfig, tpc):
                 outs += [b1, b2]
             return x, jnp.stack(outs)
 
+        if remat:
+            scan_fn = jax.checkpoint(scan_fn)
         x, st_out = jax.lax.scan(scan_fn, x, (gp_stack, st_g),
                                  unroll=scan_unroll())
         st_out = st_out.reshape(st.shape)

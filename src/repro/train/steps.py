@@ -139,10 +139,11 @@ def _pipeline_mesh(policy: CompressionPolicy, mesh, stage_axis: str):
             f"pipeline transport needs >= {s} devices, have "
             f"{jax.device_count()} — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={s} before jax init")
-    return jax.make_mesh((s,), (stage_axis,))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((s,), (stage_axis,))
 
 
-def _tp_stage_fn(cfg, mesh, tp, tp_codec, tp_k_frac, tensor_axis):
+def _tp_stage_fn(cfg, mesh, tp, tp_codec, tp_k_frac, tensor_axis, remat):
     """Stage function + extra ``pipeline_apply`` kwargs for an optional
     tensor axis.  ``tp == 1`` returns the plain dense stage fn and no
     extra kwargs; ``tp > 1`` returns a TP-sharded stage fn (compressed
@@ -150,11 +151,11 @@ def _tp_stage_fn(cfg, mesh, tp, tp_codec, tp_k_frac, tensor_axis):
     ``tp_axis``/``tp_param_dims``/``seq_dim`` kwargs pipeline_apply needs
     to extend its shard_map specs over ``tensor_axis``."""
     if tp == 1:
-        return transformer.stage_stack_fn(cfg), lambda stack: {}
+        return transformer.stage_stack_fn(cfg, remat), lambda stack: {}
     from repro.transport.tp_collectives import TPCollectives
     tpc = TPCollectives(mesh, tensor_axis, codec=tp_codec,
                         k_frac=tp_k_frac, feedback="none")
-    tp_fn = transformer.tp_stage_stack_fn(cfg, tpc)
+    tp_fn = transformer.tp_stage_stack_fn(cfg, tpc, remat)
 
     def stage_fn(gp_stack, x):
         z = jnp.zeros((0,), x.dtype)
@@ -299,7 +300,7 @@ def make_lm_train_step(cfg, policy: CompressionPolicy,
             dp=dp, dp_codec=dp_codec, dp_feedback=dp_feedback,
             dp_k_frac=dp_k_frac, data_axis=data_axis, tp=tp,
             tp_codec=t_ax.codec, tp_k_frac=t_ax.k_frac,
-            tp_feedback=t_ax.feedback, tensor_axis=tensor_axis)
+            tp_feedback=t_ax.feedback, tensor_axis=tensor_axis, remat=remat)
     if transport != "simulated":
         raise ValueError(f"unknown transport {transport!r}")
     if tp > 1:
@@ -310,7 +311,7 @@ def make_lm_train_step(cfg, policy: CompressionPolicy,
             dp_codec=dp_codec, dp_feedback=dp_feedback,
             dp_k_frac=dp_k_frac, data_axis=data_axis,
             tp_codec=t_ax.codec, tp_feedback=t_ax.feedback,
-            tp_k_frac=t_ax.k_frac, tensor_axis=tensor_axis)
+            tp_k_frac=t_ax.k_frac, tensor_axis=tensor_axis, remat=remat)
 
     def loss_fn(params, bw_bufs, fw_bufs, batch, ids):
         bstates = _merge_states(fw_bufs, bw_bufs)
@@ -443,7 +444,7 @@ def _make_tp_lm_train_step(cfg, policy: CompressionPolicy,
                            tp_codec: str = "none",
                            tp_feedback: str = "none",
                            tp_k_frac: float = 0.1,
-                           tensor_axis: str = "tensor"):
+                           tensor_axis: str = "tensor", remat: bool = True):
     """LM training with the dense layer stack sharded over the tensor
     ring (transport/tp_collectives.py), optionally composed with the
     compressed DP gradient all-reduce on a ``(data, 1, tensor)`` mesh.
@@ -471,7 +472,7 @@ def _make_tp_lm_train_step(cfg, policy: CompressionPolicy,
                                   tensor_axis=tensor_axis))
     tpc = TPCollectives(mesh, tensor_axis, codec=tp_codec, k_frac=tp_k_frac,
                         feedback=tp_feedback)
-    stage_fn = transformer.tp_stage_stack_fn(cfg, tpc)
+    stage_fn = transformer.tp_stage_stack_fn(cfg, tpc, remat)
     sites = transformer.tp_sites(cfg)
 
     def forward(params, stack_in, batch, tp_state):
@@ -534,7 +535,8 @@ def _make_pipeline_lm_train_step(cfg, policy: CompressionPolicy,
                                  tp_codec: str = "none",
                                  tp_feedback: str = "none",
                                  tp_k_frac: float = 0.1,
-                                 tensor_axis: str = "tensor"):
+                                 tensor_axis: str = "tensor",
+                                 remat: bool = True):
     """LM training through the real compressed ``ppermute`` pipeline.
 
     Same ``step(params, opt_state, bstates, batch, ids)`` signature as the
@@ -572,7 +574,8 @@ def _make_pipeline_lm_train_step(cfg, policy: CompressionPolicy,
             schedule=schedule, virtual_stages=virtual_stages, dp=dp,
             dp_codec=dp_codec, dp_feedback=dp_feedback,
             dp_k_frac=dp_k_frac, s_stages=s_stages, tp=tp,
-            tp_codec=tp_codec, tp_k_frac=tp_k_frac, tensor_axis=tensor_axis)
+            tp_codec=tp_codec, tp_k_frac=tp_k_frac, tensor_axis=tensor_axis,
+            remat=remat)
     if tp > 1:
         from repro.launch.mesh import make_3d_mesh
         if mesh is None:
@@ -582,7 +585,7 @@ def _make_pipeline_lm_train_step(cfg, policy: CompressionPolicy,
     else:
         mesh = _pipeline_mesh(policy, mesh, stage_axis)
     stage_fn, tp_kwargs = _tp_stage_fn(cfg, mesh, tp, tp_codec, tp_k_frac,
-                                       tensor_axis)
+                                       tensor_axis, remat)
 
     def forward(params, batch, fw_state, bw_state, ids):
         labels = jnp.roll(batch["tokens"], -1, axis=1)
@@ -636,7 +639,8 @@ def _make_dp_pipeline_lm_train_step(cfg, bp, opt: OptimizerConfig, *, mesh,
                                     dp_k_frac: float, s_stages: int,
                                     tp: int = 1, tp_codec: str = "none",
                                     tp_k_frac: float = 0.1,
-                                    tensor_axis: str = "tensor"):
+                                    tensor_axis: str = "tensor",
+                                    remat: bool = True):
     """LM training on the 2D ``(data, stages)`` mesh: every replica row
     pipelines its contiguous batch shard through the compressed
     ``ppermute`` wire, and the per-replica LAYER-STACK gradients cross the
@@ -667,7 +671,7 @@ def _make_dp_pipeline_lm_train_step(cfg, bp, opt: OptimizerConfig, *, mesh,
             mesh, data_axis, dp_codec, k_frac=dp_k_frac,
             feedback=dp_feedback, average=False, shard_axis=stage_axis)
     stage_fn, tp_kwargs = _tp_stage_fn(cfg, mesh, tp, tp_codec, tp_k_frac,
-                                       tensor_axis)
+                                       tensor_axis, remat)
     n_slices = s_stages * virtual_stages
     needs_state = bp.needs_fw_buffer or bp.needs_bw_buffer
 

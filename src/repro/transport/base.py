@@ -43,15 +43,11 @@ from repro.transport.codecs import WireCodec, codec_for
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` moved between jax versions; replication checking
-    is off either way (payload pytrees confuse it).  Shared by the pipeline
-    transport and the DP gradient collectives."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off (payload pytrees
+    confuse it).  Shared by the pipeline transport and the DP/TP
+    collectives."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class Transport:

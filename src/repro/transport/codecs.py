@@ -24,9 +24,11 @@ On TPU the codec hot path routes through the fused Pallas wire kernels
 (see the README "Kernels" section): ``q8`` via kernels/quantize.py
 (per-tile scales) when the flattened shape tiles into 128-lane blocks,
 ``q4`` via kernels/pack4.py and TopK via kernels/topk_select.py (both
-per-tensor, byte- resp. set-identical to the jnp formats), and multi-leaf
-payload framing via kernels/framing.py.  Everywhere else — and whenever a
-shape fails a kernel's tiling/VMEM guard — the pure-jnp path is used.
+per-tensor, byte- resp. set-identical to the jnp formats, at any shape),
+and multi-leaf payload framing via kernels/framing.py.  Off the TPU, and
+for a q8 shape with no 8-row tiling or a hop buffer over the framing
+kernel's VMEM guard, the pure-jnp path is used (``wire_route`` says
+which).
 """
 from __future__ import annotations
 
@@ -113,13 +115,6 @@ def _pallas_tiling(flat_shape) -> Optional[Tuple[int, int]]:
     return wire_tiling(flat_shape)
 
 
-def _fullrow_fits(n: int, bytes_per_elem: int = 4) -> bool:
-    """Can a full-feature-dim row block (q4 / TopK kernels) stay within
-    the per-instance VMEM budget at bm=1?"""
-    from repro.kernels.tiling import VMEM_BUDGET
-    return 0 < n * bytes_per_elem <= VMEM_BUDGET
-
-
 class QuantCodec(WireCodec):
     """Uniform k-bit min-max quantization; 4-bit packs two codes per byte.
 
@@ -142,15 +137,13 @@ class QuantCodec(WireCodec):
     def pack(self, x, k_frac: float = 1.0):
         b = x.shape[0]
         flat = x.reshape(b, -1)
-        if self.bits == 8 and _use_pallas_wire():
-            tiling = _pallas_tiling(flat.shape)
-            if tiling is not None:
-                from repro.kernels.quantize import quantize_wire
-                codes, meta = quantize_wire(flat.astype(jnp.float32), 8,
-                                            block=tiling)
-                return {"codes": codes, "tile_meta": meta}
-        if (self.bits == 4 and _use_pallas_wire()
-                and _fullrow_fits(flat.shape[1])):
+        pallas = wire_route(self.name, x.shape) == "pallas"
+        if self.bits == 8 and pallas:
+            from repro.kernels.quantize import quantize_wire
+            codes, meta = quantize_wire(flat.astype(jnp.float32), 8,
+                                        block=_pallas_tiling(flat.shape))
+            return {"codes": codes, "tile_meta": meta}
+        if self.bits == 4 and pallas:
             from repro.kernels.pack4 import pack4_wire
             packed, mn, sc = pack4_wire(flat.astype(jnp.float32))
             return {"codes4": packed, "min": mn, "scale": sc}
@@ -171,7 +164,7 @@ class QuantCodec(WireCodec):
         n = _flat_n(shape)
         if "codes4" in payload:
             packed = payload["codes4"]
-            if _use_pallas_wire() and _fullrow_fits(n):
+            if _use_pallas_wire():
                 from repro.kernels.pack4 import unpack4_wire
                 flat = unpack4_wire(packed, payload["min"],
                                     payload["scale"], n)
@@ -215,7 +208,7 @@ class TopKCodec(WireCodec):
         b = x.shape[0]
         flat = x.reshape(b, -1)
         n = flat.shape[1]
-        if _use_pallas_wire() and _fullrow_fits(n):
+        if wire_route(self.name, x.shape) == "pallas":
             from repro.kernels.topk_select import topk_select_wire
             k = max(1, int(round(k_frac * n)))   # same k as the jnp path
             vals, idx = topk_select_wire(flat, k)
@@ -239,6 +232,18 @@ class TopKCodec(WireCodec):
 def _use_pallas_wire() -> bool:
     from repro.core.compressors import _use_pallas
     return _use_pallas()
+
+
+def wire_route(name: str, shape) -> str:
+    """Which path packs a ``shape`` tensor with codec ``name`` on this
+    backend: ``"pallas"`` (a wire kernel) or ``"jnp"``.  Only q8 has a
+    shape guard: it needs an 8-row tiling (so the per-request ``(1, n)``
+    serve payloads stay jnp); ``none`` is a cast and never a kernel."""
+    if name == "none" or not _use_pallas_wire():
+        return "jnp"
+    if name == "q8" and _pallas_tiling((shape[0], _flat_n(shape))) is None:
+        return "jnp"
+    return "pallas"
 
 
 # ---------------------------------------------------------------------------

@@ -782,11 +782,16 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x, mesh: Mesh,
         oe[2 + len(rep) + seq_dim] = tp_axis
         out_spec = P(*oe)
     st_spec = lambda st: jax.tree.map(lambda _: st_axes, st)
-    out, new_fw = _shard_map(
+    # jit even when called eagerly: differentiating an eager shard_map
+    # evaluates its primal half op by op, and JAX then rejects the
+    # replicated sharding XLA gives the zero-size residuals (the empty
+    # feedback buffers) against their stage-sharded out_specs.  Inside
+    # a jitted caller this jit is inlined.
+    out, new_fw = jax.jit(_shard_map(
         body, mesh,
         (pspec, x_spec, st_spec(fw_c), st_spec(bw_c), ids_spec),
         (out_spec, st_spec(fw_c)),
-    )(params_dev, x_mb, fw_c, bw_c, ids_mb)
+    ))(params_dev, x_mb, fw_c, bw_c, ids_mb)
     out = out[-1].reshape(b, *x.shape[1:])
     if with_state:
         return out, fw_state.replace(resid=new_fw["resid"],

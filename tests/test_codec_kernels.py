@@ -25,7 +25,8 @@ from conftest import hypothesis_or_stubs
 given, settings, st = hypothesis_or_stubs()
 
 import repro.core.compressors as C
-from repro.kernels.tiling import full_row_block, pow2_row_block, wire_tiling
+from repro.kernels.tiling import (VMEM_BUDGET, column_tiling,
+                                  pow2_row_block, wire_tiling)
 from repro.transport import codecs
 
 
@@ -80,10 +81,17 @@ class TestTiling:
         assert codecs._pallas_tiling((13, 256)) is None
 
     def test_full_row_block_divides_and_fits(self):
+        # column_tiling replaced full_row_block: every block is legal for
+        # Mosaic (rows a multiple of 8 or the whole m, lanes a multiple
+        # of 128), divides the operand and fits the VMEM budget
         for m in (1, 2, 12, 48, 256, 1000):
-            for n in (7, 129, 4096):
-                bm = full_row_block(m, n)
-                assert m % bm == 0 and bm >= 1
+            for n in (128, 384, 4096, 786432):
+                bm, bn = column_tiling(m, n)
+                assert m % bm == 0 and (bm % 8 == 0 or bm == m)
+                assert n % bn == 0 and bn % 128 == 0
+                assert bm * bn * 4 <= VMEM_BUDGET or bn == 128
+        assert column_tiling(8, 786432, max_lanes=32768) == (8, 32768)
+        assert column_tiling(4, 512, lane_multiple=256) == (4, 512)
 
 
 # ---------------------------------------------------------------------------

@@ -254,14 +254,14 @@ DP_ACCEPT_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import make_dp_pipeline_mesh
+    from repro.launch.mesh import make_dp_pipeline_mesh, make_mesh
     from repro.transport.pipeline import pipeline_apply
     from repro.transport.collectives import (dp_wire_report, init_dp_state,
                                              make_grad_all_reduce)
 
     DP, S, B, D, MB = 2, 2, 8, 16, 2
     mesh = make_dp_pipeline_mesh(DP, S)
-    mesh1 = jax.make_mesh((S,), ("stage",))
+    mesh1 = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
     params0 = {"w1": jax.random.normal(k1, (S, D, 2 * D)) * 0.1,

@@ -23,6 +23,7 @@ import pytest
 
 from repro.core.policy import (CompressionPolicy, parse_policy_rules,
                                quant_policy, resolve_policy, topk_policy)
+from repro.launch.mesh import make_mesh
 from repro.obs import trace
 from repro.obs.export import (EVENT_SCHEMA, to_chrome_trace, to_jsonl,
                               validate_events, validate_jsonl)
@@ -193,7 +194,7 @@ class TestProbeKeying:
         assert key == "{{0,2},{1,3},{2,0},{3,1}}"
 
     def test_ring_pairs_on_1d_mesh(self):
-        mesh = jax.make_mesh((jax.device_count(),), ("stage",))
+        mesh = make_mesh((jax.device_count(),), ("stage",))
         n = jax.device_count()
         pairs = ring_pairs(mesh, "stage")
         ids = [d.id for d in np.asarray(mesh.devices).ravel()]

@@ -83,7 +83,8 @@ PIPE_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import numpy as np, jax, jax.numpy as jnp
     from repro.core.pipeline import pipeline_forward
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     B, D = 8, 64
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (B, D), jnp.float32)

@@ -261,3 +261,21 @@ class TestTrainDriver:
                    "--batch", "2", "--prompt-len", "8", "--new-tokens", "4",
                    "--max-seq", "32"])
         assert rc == 0
+
+    def test_compile_cache_placement(self, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR wins and no other directory is set
+        in code; without it the cache goes to <checkout>/.jax_cache."""
+        from repro.launch import compile_cache
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            jax.config.update("jax_compilation_cache_dir", "unchanged")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == "unchanged"
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+            where = compile_cache.enable_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert where == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == where
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)

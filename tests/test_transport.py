@@ -17,6 +17,7 @@ given, settings, st = hypothesis_or_stubs()
 
 from repro.core.compressors import (quant, quantize_dequantize, topk,
                                     topk_compress)
+from repro.launch.mesh import make_mesh
 from repro.transport.codecs import (codec_for, get_codec, pack_payload,
                                     registered_codecs, unpack_payload,
                                     wire_bytes)
@@ -141,7 +142,8 @@ GRAD_EQUIV_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.transport.pipeline import pipeline_apply
     S, B, D = 2, 4, 16
-    mesh = jax.make_mesh((S,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (B, D), jnp.float32)
     k1, k2 = jax.random.split(key)
@@ -272,7 +274,8 @@ FEEDBACK_COMMON = textwrap.dedent("""
 
     S, B, D, MB = 2, 4, 16, 2
     MBSZ = B // MB
-    mesh = jax.make_mesh((S,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
     params0 = {"w1": jax.random.normal(k1, (S, D, 2 * D)) * 0.1,
@@ -462,7 +465,8 @@ FEEDBACK_DP_SCRIPT = textwrap.dedent("""
     DP, S, B, D, MB = 2, 2, 8, 16, 2
     SH = B // DP                          # per-replica shard
     mesh = make_dp_pipeline_mesh(DP, S)
-    mesh1 = jax.make_mesh((S,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh1 = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
     params0 = {"w1": jax.random.normal(k1, (S, D, 2 * D)) * 0.1,
@@ -628,7 +632,8 @@ SCHEDULE_EQUIV_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.transport.pipeline import pipeline_apply
     S, B, D, MB = 2, 8, 16, 8
-    mesh = jax.make_mesh((S,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
     params = {"w1": jax.random.normal(k1, (S, D, 2 * D)) * 0.1,
@@ -688,7 +693,8 @@ SCHEDULE_INTERLEAVED_SCRIPT = textwrap.dedent("""
     S, V, B, D, MB = 2, 2, 8, 16, 4
     MBSZ = B // MB
     L = S * V
-    mesh = jax.make_mesh((S,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
     pL = {"w1": jax.random.normal(k1, (L, D, 2 * D)) * 0.1,
@@ -999,7 +1005,7 @@ class TestSchedulePlans:
     def test_nonpositive_microbatches_rejected(self):
         """Satellite: microbatches=0 used to silently mean 'stage count'."""
         from repro.transport.pipeline import pipeline_apply
-        mesh = jax.make_mesh((1,), ("stage",))
+        mesh = make_mesh((1,), ("stage",))
         params = {"w": jnp.zeros((1, 4, 4))}
         x = jnp.zeros((4, 4))
         fn = lambda p, h: h @ p["w"]
@@ -1010,7 +1016,7 @@ class TestSchedulePlans:
 
     def test_params_leading_dim_checked(self):
         from repro.transport.pipeline import pipeline_apply
-        mesh = jax.make_mesh((1,), ("stage",))
+        mesh = make_mesh((1,), ("stage",))
         params = {"w": jnp.zeros((3, 4, 4))}    # not S*v = 2
         x = jnp.zeros((4, 4))
         with pytest.raises(ValueError, match="leading dim"):
