@@ -1,0 +1,2 @@
+"""The chip benchmark: ``python bench/run.py --workload <cell> --seed <n>
+--seconds <s> --trace <0|1>`` (see BENCHMARK.json and harness.py)."""
