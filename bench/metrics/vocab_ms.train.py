@@ -1,0 +1,10 @@
+"""Device self time a training step in the vocabulary: embedding lookup,
+tied head and chunked loss (repro/models/transformer.py, scope `vocab`),
+in ms: all phases, the mean over the cell's devices over the traced
+steps (bench/scopes.py). No reading where the window holds no op of the
+scope."""
+from bench import scopes
+
+
+def read(ctx):
+    return scopes.layer_ms(ctx, "vocab")

@@ -553,15 +553,11 @@ def measure_tp(codecs=("none", "q8", "q4", "topk"), *, tp=2, batch=4,
 
 def measure_telemetry(schemes=("none", "q8", "q4", "topk", "topk_reuse"),
                       *, stages=4, batch=8, seq=256, d_model=256,
-                      k_frac=0.10, steps=10, check: bool = True):
-    """§Telemetry: (a) the tracer's per-boundary "pipeline.wire" payload
-    bytes agree EXACTLY with this benchmark's own cost-model numbers
+                      k_frac=0.10, check: bool = True):
+    """§Telemetry: the tracer's per-boundary "pipeline.wire" payload bytes
+    agree EXACTLY with this benchmark's own cost-model numbers
     (:func:`payload_bytes` — two independent derivations of the same
-    eval_shape facts), per scheme; (b) tracing a jitted step costs <= 3%
-    wall time (the wire events fire at TRACE time, so steady state only
-    pays the host-side span bookkeeping).  Timing fields are excluded
-    from --check (wall-clock noise); the agreement booleans are exact."""
-    from repro.obs import trace
+    eval_shape facts), per scheme."""
     from repro.transport.pipeline import (PipelineTransport,
                                           _policy_for_scheme, wire_telemetry)
     from repro.transport.schedules import as_schedule
@@ -586,50 +582,6 @@ def measure_telemetry(schemes=("none", "q8", "q4", "topk", "topk_reuse"),
             "cost_model_fw_bytes": fw, "cost_model_bw_bytes": bw,
             "agree_exactly": agree,
         })
-
-    # -- enabled-tracing overhead on a real jitted pipeline step ------------
-    from repro.transport.pipeline import pipeline_apply
-    import time
-    mesh = make_mesh((stages,), ("stage",))
-    params = {"w": jnp.full((stages, 1, 1), 1.0, jnp.bfloat16)}
-
-    def run(p, xx):
-        return pipeline_apply(lambda sp, h: h * sp["w"], p, xx, mesh,
-                              "stage", scheme="q8", k_frac=k_frac)
-
-    # a small step keeps the whole section fast; the span's ~µs cost is
-    # RELATIVELY largest against a small step, so the gate is conservative
-    x = jnp.ones((batch, 32, 64), jnp.bfloat16)
-    fn = jax.jit(run)
-    jax.block_until_ready(fn(params, x))                 # compile + warm
-
-    def timed(enabled: bool) -> float:
-        (trace.enable if enabled else trace.disable)()
-        t0 = time.perf_counter()
-        for step in range(steps):
-            with trace.span("train.step", cat="train", step=step):
-                jax.block_until_ready(fn(params, x))
-        return time.perf_counter() - t0
-
-    # interleaved off/on pairs: ambient machine load hits both halves of
-    # a pair about equally, so the BEST pair ratio isolates the span's
-    # ~µs bookkeeping from scheduler noise on a busy runner
-    pairs = [(timed(False), timed(True)) for _ in range(5)]
-    trace.disable()
-    off = min(o for o, _ in pairs)
-    on = min(n for _, n in pairs)
-    ratio = min(n / o for o, n in pairs)
-    overhead = ratio - 1.0
-    # 3% relative plus a 5ms absolute floor for very fast steps
-    ok = ratio <= 1.03 or on <= off + 0.005
-    if check:
-        assert ok, (on, off, overhead, pairs)
-    reports.append({
-        "scheme": "overhead", "steps": steps,
-        "seconds_off": round(off, 4), "seconds_on": round(on, 4),
-        "overhead_pct": round(100.0 * overhead, 2),
-        "within_3pct": ok,
-    })
     return reports
 
 
@@ -718,8 +670,7 @@ def main(argv=None):
         return run_check(
             fresh, "pipeline_wire",
             band_keys={"hlo_fw_collective_permute_bytes": 0.25,
-                       "hlo_fwbw_collective_permute_bytes": 0.25},
-            ignore_keys={"seconds_off", "seconds_on", "overhead_pct"})
+                       "hlo_fwbw_collective_permute_bytes": 0.25})
     os.makedirs(os.path.join(os.path.dirname(__file__), "results"),
                 exist_ok=True)
     with open(os.path.join(os.path.dirname(__file__), "results",

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.policy import BoundaryPolicy
+from repro.obs.trace import scope
 
 
 def _transport(policy: BoundaryPolicy):
@@ -75,6 +76,7 @@ def _boundary_bwd(policy: BoundaryPolicy, residuals, cotangents):
 boundary_apply.defvjp(_boundary_fwd, _boundary_bwd)
 
 
+@scope("codec")
 def boundary_eval(policy: BoundaryPolicy, x, compress: bool):
     """Inference-time boundary: plain fw compressor or identity.
 
@@ -84,6 +86,7 @@ def boundary_eval(policy: BoundaryPolicy, x, compress: bool):
     return policy.fw(x) if compress else x
 
 
+@scope("codec")
 def boundary_wire_eval(policy: BoundaryPolicy, x, compress: bool):
     """Serve-time boundary through the wire-codec registry.
 
@@ -113,6 +116,7 @@ def boundary_wire_eval(policy: BoundaryPolicy, x, compress: bool):
     return jax.vmap(one)(x)
 
 
+@scope("codec")
 def boundary_wire_eval_tokens(policy: BoundaryPolicy, x, compress: bool):
     """Per-(request, token) wire packing for multi-token decode spans.
 

@@ -53,7 +53,11 @@ def zipf_lengths(rng, n, lo, hi, a=1.6):
 
 
 def _export_trace(args) -> None:
-    """Drain the tracer into the requested --trace / --perfetto files."""
+    """Close the --profile capture and drain the tracer into the --trace
+    file."""
+    if args.profile:
+        jax.profiler.stop_trace()
+        print(f"# profile: -> {args.profile}", flush=True)
     from repro.obs import trace as obs_trace
     tr = obs_trace.get_tracer()
     if tr is None:
@@ -63,10 +67,6 @@ def _export_trace(args) -> None:
         from repro.obs.export import to_jsonl
         print(f"# trace: {to_jsonl(events, args.trace)} events "
               f"-> {args.trace} (dropped {tr.dropped})", flush=True)
-    if args.perfetto:
-        from repro.obs.export import to_chrome_trace
-        print(f"# perfetto: {to_chrome_trace(events, args.perfetto)} "
-              f"events -> {args.perfetto}", flush=True)
 
 
 def main(argv=None) -> int:
@@ -131,9 +131,10 @@ def main(argv=None) -> int:
                     help="enable telemetry and write the JSONL event log "
                          "here (obs/export.py schema; default: tracing "
                          "off, zero overhead)")
-    ap.add_argument("--perfetto", default=None, metavar="PATH",
-                    help="also write a Chrome-trace JSON loadable at "
-                         "ui.perfetto.dev / chrome://tracing")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="capture the run with jax.profiler into DIR (an "
+                         "xplane and a Perfetto trace): device ops beside "
+                         "the serve.* spans on one clock; implies tracing")
     ap.add_argument("--metrics", type=int, default=1, metavar="N",
                     help="continuous engine: emit scheduler/page-pool "
                          "counters every N ticks when tracing is on "
@@ -141,10 +142,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    tracing = bool(args.trace or args.perfetto)
+    tracing = bool(args.trace or args.profile)
     if tracing:
         from repro.obs import trace as obs_trace
         obs_trace.enable()
+    if args.profile:
+        jax.profiler.start_trace(args.profile, create_perfetto_trace=True)
 
     cfg = get(args.arch, smoke=args.smoke)
     unsupported = left_pad_unsupported(cfg)

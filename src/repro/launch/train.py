@@ -260,9 +260,11 @@ def main(argv=None) -> int:
                     help="enable telemetry and write the JSONL event log "
                          "here (obs/export.py schema; default: tracing "
                          "off, zero overhead)")
-    ap.add_argument("--perfetto", default=None, metavar="PATH",
-                    help="also write a Chrome-trace JSON loadable at "
-                         "ui.perfetto.dev / chrome://tracing")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="capture the run with jax.profiler into DIR (an "
+                         "xplane and a Perfetto trace): device ops beside "
+                         "the train.step spans on one clock; implies "
+                         "tracing")
     ap.add_argument("--metrics", type=int, default=0, metavar="N",
                     help="sample per-boundary compression error + "
                          "feedback-buffer norms every N steps (obs/"
@@ -270,7 +272,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     enable_compile_cache()
 
-    tracing = bool(args.trace or args.perfetto or args.metrics)
+    tracing = bool(args.trace or args.profile or args.metrics)
     if tracing:
         obs_trace.enable()
 
@@ -460,6 +462,8 @@ def main(argv=None) -> int:
                          seed=args.seed)
     metrics, t0 = [], time.time()
     tokens_per_step = args.batch * seq
+    if args.profile:
+        jax.profiler.start_trace(args.profile, create_perfetto_trace=True)
     for step in range(start_step + 1, args.steps + 1):
         toks, ids = next(stream)
         with obs_trace.span("train.step", cat="train", step=step) as sa:
@@ -494,6 +498,10 @@ def main(argv=None) -> int:
                        "feedback": args.feedback, "dp": dp_n,
                        "dp_codec": dp_codec_eff, "tp": tp_n},
                 dp_state=dp_state)
+    if args.profile:
+        jax.block_until_ready(params)
+        jax.profiler.stop_trace()
+        print(f"# profile: -> {args.profile}", flush=True)
     if args.json:
         with open(args.json, "w") as f:
             json.dump(metrics, f, indent=1)
@@ -504,10 +512,6 @@ def main(argv=None) -> int:
             from repro.obs.export import to_jsonl
             print(f"# trace: {to_jsonl(events, args.trace)} events "
                   f"-> {args.trace} (dropped {tr.dropped})", flush=True)
-        if args.perfetto:
-            from repro.obs.export import to_chrome_trace
-            print(f"# perfetto: {to_chrome_trace(events, args.perfetto)} "
-                  f"events -> {args.perfetto}", flush=True)
     print("# done: final loss "
           f"{metrics[-1]['loss'] if metrics else 'n/a (already at --steps)'}",
           flush=True)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import DTYPE, apply_rope, dense_init, softcap
+from repro.obs.trace import scope
 from repro.sharding.ctx import constrain
 
 
@@ -85,6 +86,7 @@ def _causal_mask(s: int, window: Optional[int], positions) -> jnp.ndarray:
     return m[None]
 
 
+@scope("attn")
 def attn_train(params, x, *, num_heads, num_kv_heads, head_dim,
                pos_embed="rope", rope_theta=10_000.0, window=None,
                attn_softcap=None, positions=None, pad_mask=None):
@@ -117,6 +119,7 @@ def tp_local_heads(num_heads, num_kv_heads, tp):
     return num_heads // tp, num_kv_heads // tp
 
 
+@scope("attn")
 def attn_train_tp(params, x_shard, tpc, *, num_heads, num_kv_heads,
                   head_dim, pos_embed="rope", rope_theta=10_000.0,
                   window=None, attn_softcap=None, buf=None):
@@ -151,6 +154,7 @@ def init_cache(batch: int, cache_len: int, num_kv_heads: int, head_dim: int,
     return {"k": k, "v": v}
 
 
+@scope("attn")
 def attn_decode(params, x1, cache, pos, *, num_heads, num_kv_heads, head_dim,
                 pos_embed="rope", rope_theta=10_000.0, window=None,
                 attn_softcap=None, pad_len=None):
@@ -214,6 +218,7 @@ def attn_decode(params, x1, cache, pos, *, num_heads, num_kv_heads, head_dim,
     return out @ params["wo"], {"k": ck, "v": cv}
 
 
+@scope("attn")
 def attn_decode_span(params, x, cache, pos, *, num_heads, num_kv_heads,
                      head_dim, pos_embed="rope", rope_theta=10_000.0,
                      window=None, attn_softcap=None, pad_len=None,
@@ -276,6 +281,7 @@ def attn_decode_span(params, x, cache, pos, *, num_heads, num_kv_heads,
     return out @ params["wo"], {"k": ck, "v": cv}
 
 
+@scope("attn")
 def attn_prefill(params, x, *, cache_len, num_heads, num_kv_heads, head_dim,
                  pos_embed="rope", rope_theta=10_000.0, window=None,
                  attn_softcap=None, pad_mask=None):
@@ -315,6 +321,7 @@ def cross_attn_init(key, d: int, num_heads: int, head_dim: int, dtype=DTYPE):
     return attn_init(key, d, num_heads, num_heads, head_dim, dtype)
 
 
+@scope("attn")
 def cross_attn(params, x, memory, *, num_heads, head_dim):
     """x: (B,S,d) queries; memory: (B,T,d) encoder output (non-causal)."""
     b, s, _ = x.shape

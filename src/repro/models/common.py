@@ -12,6 +12,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import scope
+
 DTYPE = jnp.bfloat16
 
 
@@ -41,6 +43,7 @@ def norm_init(d: int, kind: str = "rmsnorm"):
             "bias": jnp.zeros((d,), jnp.float32)}
 
 
+@scope("norm")
 def norm_apply(params, x: jnp.ndarray, kind: str = "rmsnorm",
                eps: float = 1e-6) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
@@ -68,6 +71,7 @@ def mlp_init(key, d: int, ff: int, kind: str = "swiglu", dtype=DTYPE):
             "wo": dense_init(ks[2], ff, d, dtype)}
 
 
+@scope("mlp")
 def mlp_apply(params, x: jnp.ndarray, kind: str = "swiglu") -> jnp.ndarray:
     if kind == "swiglu":
         h = jax.nn.silu(x @ params["wg"]) * (x @ params["wi"])

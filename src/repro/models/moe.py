@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import DTYPE, dense_init, mlp_apply, mlp_init
+from repro.obs.trace import scope
 from repro.sharding.ctx import constrain
 
 GROUP_SIZE = 4096        # tokens per routing group (MaxText-like)
@@ -156,6 +157,7 @@ def _moe_apply_dense(params, x: jnp.ndarray, *, num_experts: int,
     return y.reshape(b, s, d), aux
 
 
+@scope("mlp")
 def moe_apply(params, x: jnp.ndarray, *, num_experts: int, top_k: int,
               mlp_kind: str, capacity_factor: float = 1.25,
               group_size: int = GROUP_SIZE, dispatch_quant: bool = False,

@@ -32,6 +32,7 @@ from repro.models import blocks as B
 from repro.models.common import DTYPE, embed_init, norm_apply, norm_init, softcap
 from repro.models.config import ModelConfig
 from repro.models.scan_config import scan_unroll
+from repro.obs.trace import scope
 from repro.sharding.ctx import constrain
 
 
@@ -86,6 +87,7 @@ def _embed_lookup(embed, tokens):
     return constrain(out, "batch", None, None)
 
 
+@scope("vocab")
 def _embed_input(params, batch, cfg: ModelConfig):
     """batch: {"tokens": (B,S)} (+ "patch_embeds": (B,P,d) for vlm)."""
     tokens = batch["tokens"]
@@ -98,6 +100,7 @@ def _embed_input(params, batch, cfg: ModelConfig):
     return x
 
 
+@scope("vocab")
 def _lm_logits(params, x, cfg: ModelConfig):
     """Logits in bf16 (fp32 MXU accumulation, downcast fused into the
     matmul) — materializing fp32 (B,S,V) costs 4x the HBM of the weights
@@ -157,13 +160,16 @@ def forward_hidden(params, batch, cfg: ModelConfig,
             x, a = carry
             x, ai = group_fn(x, gp)
             return (x, a + ai), None
-        (x, aux), _ = jax.lax.scan(scan_fn, (x, aux),
-                                   _slice_groups(params["layers"], g0, g1), unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            (x, aux), _ = jax.lax.scan(
+                scan_fn, (x, aux), _slice_groups(params["layers"], g0, g1),
+                unroll=scan_unroll())
         if si < len(segs) - 1:
             bp = policy.at(si)
             st = (bstates[si] if bstates is not None
                   else empty_boundary_state(x.dtype))
-            x, nf = boundary_apply(bp, x, st["fw"], st["bw"], ids)
+            with jax.named_scope("codec"):
+                x, nf = boundary_apply(bp, x, st["fw"], st["bw"], ids)
             new_fw.append(nf)
     return x, aux, new_fw
 
@@ -192,7 +198,8 @@ def stage_stack_fn(cfg: ModelConfig, remat: bool = True):
             return x, None
         if remat:
             scan_fn = jax.checkpoint(scan_fn)
-        x, _ = jax.lax.scan(scan_fn, x, gp_stack, unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(scan_fn, x, gp_stack, unroll=scan_unroll())
         return x
 
     return stage_fn
@@ -268,7 +275,9 @@ def tp_stage_stack_fn(cfg: ModelConfig, tpc, remat: bool = True):
                 return x, None
             if remat:
                 scan_fn = jax.checkpoint(scan_fn)
-            x, _ = jax.lax.scan(scan_fn, x, gp_stack, unroll=scan_unroll())
+            with jax.named_scope("layers"):
+                x, _ = jax.lax.scan(scan_fn, x, gp_stack,
+                                    unroll=scan_unroll())
             return x, resid, mirror
 
         st = resid if tpc.feedback == "ef" else mirror
@@ -287,8 +296,9 @@ def tp_stage_stack_fn(cfg: ModelConfig, tpc, remat: bool = True):
 
         if remat:
             scan_fn = jax.checkpoint(scan_fn)
-        x, st_out = jax.lax.scan(scan_fn, x, (gp_stack, st_g),
-                                 unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, st_out = jax.lax.scan(scan_fn, x, (gp_stack, st_g),
+                                     unroll=scan_unroll())
         st_out = st_out.reshape(st.shape)
         if tpc.feedback == "ef":
             return x, st_out, mirror
@@ -297,6 +307,7 @@ def tp_stage_stack_fn(cfg: ModelConfig, tpc, remat: bool = True):
     return stage_fn
 
 
+@scope("vocab")
 def hidden_lm_loss(params, x, labels, cfg: ModelConfig,
                    mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Chunked cross-entropy straight from hidden states: the (B,S,V)
@@ -336,8 +347,10 @@ def forward_eval(params, batch, cfg: ModelConfig,
             for i, kind in enumerate(kinds):
                 x, _ = B.block_train(gp[f"b{i}"], x, cfg, kind)
             return constrain(x, "batch", "model", None), None
-        x, _ = jax.lax.scan(scan_fn, x,
-                            _slice_groups(params["layers"], g0, g1), unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(scan_fn, x,
+                                _slice_groups(params["layers"], g0, g1),
+                                unroll=scan_unroll())
         if si < len(segs) - 1:
             x = beval(policy.at(si), x, compress)
     return _lm_logits(params, x, cfg)
@@ -382,8 +395,10 @@ def prefill(params, batch, cfg: ModelConfig,
                                           cache_len, pad_mask=pad_mask)
                 cs[f"b{i}"] = c
             return constrain(x, "batch", "model", None), cs
-        x, cseg = jax.lax.scan(scan_fn, x,
-                               _slice_groups(params["layers"], g0, g1), unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, cseg = jax.lax.scan(scan_fn, x,
+                                   _slice_groups(params["layers"], g0, g1),
+                                   unroll=scan_unroll())
         cache_segs.append(cseg)
         if si < len(segs) - 1:
             x = beval(policy.at(si), x, compress)
@@ -401,7 +416,8 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig,
     ``wire=True``: stage cuts pack/unpack the real codec payloads."""
     kinds = cfg.layer_kinds()
     beval = boundary_wire_eval if wire else boundary_eval
-    x = params["embed"][token][:, None].astype(DTYPE)
+    with jax.named_scope("vocab"):
+        x = params["embed"][token][:, None].astype(DTYPE)
     x = constrain(x, "batch", None, "model")
     segs = segment_bounds(cfg.num_groups, policy.num_stages)
     new_segs = []
@@ -414,8 +430,11 @@ def decode_step(params, token, caches, pos, cfg: ModelConfig,
                                       cfg, kind, pad_len=pad_len)
                 new_c[f"b{i}"] = c
             return constrain(x, "batch", "model", None), new_c
-        x, nseg = jax.lax.scan(scan_fn, x, (_slice_groups(params["layers"], g0, g1),
-                         _slice_groups(caches, g0, g1)), unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, nseg = jax.lax.scan(
+                scan_fn, x, (_slice_groups(params["layers"], g0, g1),
+                             _slice_groups(caches, g0, g1)),
+                unroll=scan_unroll())
         new_segs.append(nseg)
         if si < len(segs) - 1:
             x = beval(policy.at(si), x, compress)
@@ -452,7 +471,8 @@ def decode_span(params, tokens, caches, pos, cfg: ModelConfig,
             "(wire=True) — the serve engines never use the in-process "
             "boundary at decode time")
     kinds = cfg.layer_kinds()
-    x = params["embed"][tokens].astype(DTYPE)             # (B, T, d)
+    with jax.named_scope("vocab"):
+        x = params["embed"][tokens].astype(DTYPE)         # (B, T, d)
     x = constrain(x, "batch", None, "model")
     segs = segment_bounds(cfg.num_groups, policy.num_stages)
     new_segs = []
@@ -466,10 +486,11 @@ def decode_span(params, tokens, caches, pos, cfg: ModelConfig,
                     pad_len=pad_len, page_map=page_map, valid_len=valid_len)
                 new_c[f"b{i}"] = c
             return constrain(x, "batch", "model", None), new_c
-        x, nseg = jax.lax.scan(
-            scan_fn, x, (_slice_groups(params["layers"], g0, g1),
-                         _slice_groups(caches, g0, g1)),
-            unroll=scan_unroll())
+        with jax.named_scope("layers"):
+            x, nseg = jax.lax.scan(
+                scan_fn, x, (_slice_groups(params["layers"], g0, g1),
+                             _slice_groups(caches, g0, g1)),
+                unroll=scan_unroll())
         new_segs.append(nseg)
         if si < len(segs) - 1:
             x = boundary_wire_eval_tokens(policy.at(si), x, compress)
@@ -522,6 +543,7 @@ def _fx_bwd(res, g):
 _fused_xent.defvjp(_fx_fwd, _fx_bwd)
 
 
+@scope("vocab")
 def lm_loss(logits: jnp.ndarray, labels: jnp.ndarray,
             mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Next-token cross entropy.  logits: (B,S,V); labels: (B,S).

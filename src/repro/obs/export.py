@@ -1,12 +1,9 @@
-"""Trace exporters: JSONL event log + Chrome-trace (Perfetto) JSON.
+"""Trace exporter: the JSONL event log and its schema.
 
-One event schema, two serializations:
-
-  * JSONL — one event object per line, machine-diffable, streamed by the
-    CI metrics-smoke step and validated against :data:`EVENT_SCHEMA`;
-  * Chrome trace — ``{"traceEvents": [...]}`` loadable by Perfetto
-    (https://ui.perfetto.dev) and ``chrome://tracing``; spans ("X")
-    carry microsecond ts/dur, counters ("C") render as tracks.
+JSONL — one event object per line, machine-diffable, streamed by the CI
+metrics-smoke step and validated against :data:`EVENT_SCHEMA`.  For a
+timeline, take a profiler capture instead (``--profile DIR``): it holds
+the spans beside the device ops, on one clock (obs/trace.py).
 
 The schema is deliberately flat so downstream tooling needs no codegen:
 
@@ -46,33 +43,6 @@ def to_jsonl(events: Iterable, path: str) -> int:
     with open(path, "w") as f:
         for r in rows:
             f.write(json.dumps(r) + "\n")
-    return len(rows)
-
-
-def to_chrome_trace(events: Iterable, path: str, *,
-                    pid: int = 0, tid: int = 0) -> int:
-    """Write the Chrome-trace/Perfetto JSON format.
-
-    Counter args must be numeric in this format; non-numeric arg values
-    (codec names etc.) are stringified into the args dict, which both
-    viewers render in the detail pane."""
-    rows = []
-    for e in _dicts(events):
-        rec = {"name": e["name"], "cat": e["cat"], "ph": e["ph"],
-               "ts": e["ts_us"], "pid": pid, "tid": tid,
-               "args": e["args"]}
-        if e["ph"] == "X":
-            rec["dur"] = e["dur_us"]
-        if e["ph"] == "i":
-            rec["s"] = "t"                     # instant scope: thread
-        if e["ph"] == "C":
-            rec["args"] = {k: (v if isinstance(v, (int, float))
-                               and not isinstance(v, bool) else str(v))
-                           for k, v in e["args"].items()}
-        rows.append(rec)
-    with open(path, "w") as f:
-        json.dump({"traceEvents": rows,
-                   "displayTimeUnit": "ms"}, f)
     return len(rows)
 
 

@@ -9,9 +9,15 @@ Design constraints (ISSUE 9 acceptance):
   * bounded memory when enabled — a ``deque(maxlen=capacity)`` ring
     buffer drops the OLDEST events and counts the drops, so a long run
     can leave tracing on without growing without bound;
-  * exporter-agnostic events — one flat :class:`TraceEvent` record maps
-    1:1 onto both the JSONL schema and the Chrome-trace format
-    (obs/export.py).
+  * flat events — one :class:`TraceEvent` record maps 1:1 onto the
+    JSONL schema (obs/export.py);
+  * one clock with the device — when enabled, each span also opens a
+    ``jax.profiler.TraceAnnotation`` of its name, so a profiler capture
+    (``--profile DIR`` in the launchers) shows the program's spans on the
+    host timeline beside the device ops.
+
+:func:`scope` names a function's ops inside the compiled program
+(``jax.named_scope``): metadata only, no run-time cost, on or off.
 
 Usage::
 
@@ -30,12 +36,15 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, List, Optional
 
-# Chrome-trace phases we emit: X = complete span (ts + dur),
-# C = counter sample, i = instant event.
+import jax
+
+# Event phases: X = complete span (ts + dur), C = counter sample,
+# i = instant event.
 PHASES = ("X", "C", "i")
 
 
@@ -88,10 +97,12 @@ class Tracer:
         """Wall-clock a ``with`` block as one complete ("X") event.
 
         Yields the event's mutable ``args`` dict so the block can attach
-        results it only knows at the end (e.g. a loss value)."""
+        results it only knows at the end (e.g. a loss value).  The block
+        also runs under a profiler annotation of the same name."""
         t0 = self._now()
         try:
-            yield args
+            with jax.profiler.TraceAnnotation(name):
+                yield args
         finally:
             self._append(TraceEvent(name, cat, "X", t0,
                                     self._now() - t0, args))
@@ -157,3 +168,22 @@ def counter(name: str, cat: str = "default", **values) -> None:
     t = _TRACER
     if t is not None:
         t.counter(name, cat, **values)
+
+
+def scope(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``.
+
+    A fresh scope per call: ``jax.named_scope`` used as a decorator keeps
+    one context object, whose saved state a nested or concurrent trace of
+    the same function would overwrite.  The name lands in the ``op_name``
+    metadata of every HLO op the function emits, in the forward pass, its
+    transpose and remat's recompute alike; it adds no op.  The names in
+    use (bench/scopes.py reads them): ``vocab``, ``layers``, ``attn``,
+    ``mlp``, ``norm``, ``codec``, ``optimizer``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap

@@ -11,6 +11,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import scope
+
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
@@ -59,6 +61,7 @@ def _global_norm(tree) -> jnp.ndarray:
     return jnp.sqrt(jax.tree.reduce(lambda a, b: a + b, sq))
 
 
+@scope("optimizer")
 def apply_updates(cfg: OptimizerConfig, params, grads, state):
     """Returns (new_params, new_state)."""
     step = state["step"] + 1

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from repro.core.compressors import (Compressor, dequantize_kbit,
                                     quantize_kbit, topk_scatter,
                                     topk_values_indices)
+from repro.obs.trace import scope
 
 # Index dtype threshold: a flattened feature dim of up to 2**16 entries has
 # indices 0..65535, exactly the uint16 range.
@@ -310,11 +311,13 @@ def codec_for(comp: Compressor) -> WireCodec:
 # Functional wrappers (the original core/pipeline.py API)
 # ---------------------------------------------------------------------------
 
+@scope("codec")
 def pack_payload(x: jnp.ndarray, scheme: str, k_frac: float = 0.1) -> dict:
     """x: (B, ...) stage output -> wire pytree (static shapes)."""
     return get_codec(scheme).pack(x, k_frac)
 
 
+@scope("codec")
 def unpack_payload(payload: dict, shape, dtype=jnp.bfloat16) -> jnp.ndarray:
     """Inverse of :func:`pack_payload`: dispatches on the payload's EXACT
     key set, registered per codec via ``payload_keysets()``."""

@@ -4,8 +4,9 @@ Core guarantees under test:
   * the tracer is ZERO-cost when disabled (module helpers no-op, shared
     null context, no events) and bounded when enabled (ring buffer drops
     oldest, counts drops);
-  * exporters: JSONL round-trips through the schema validator; the
-    Chrome-trace JSON carries the phase-specific fields Perfetto needs;
+  * exporter: JSONL round-trips through the schema validator;
+  * one clock: an enabled span lands on the profiler's host timeline
+    (``jax.profiler.TraceAnnotation``); a disabled one opens nothing;
   * probes key ring pairs EXACTLY like ``collective_counts
     (by_pairs=True)`` keys the HLO audit — one vocabulary between the
     measurement and the compiled-program launch table;
@@ -25,8 +26,8 @@ from repro.core.policy import (CompressionPolicy, parse_policy_rules,
                                quant_policy, resolve_policy, topk_policy)
 from repro.launch.mesh import make_mesh
 from repro.obs import trace
-from repro.obs.export import (EVENT_SCHEMA, to_chrome_trace, to_jsonl,
-                              validate_events, validate_jsonl)
+from repro.obs.export import (EVENT_SCHEMA, to_jsonl, validate_events,
+                              validate_jsonl)
 from repro.obs.probes import (LinkMeasurement, boundary_bandwidth,
                               pairs_key, ring_pairs)
 from repro.obs.quality import QualityTap, feedback_norms, relative_error
@@ -84,6 +85,45 @@ class TestTracer:
         with pytest.raises(ValueError, match="capacity"):
             trace.enable(capacity=0)
 
+    def test_span_lands_on_the_profiler_host_timeline(self, tmp_path):
+        import glob
+        from jax.profiler import ProfileData
+        trace.enable()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("obs.test_span", cat="t"):
+                jnp.ones(8).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        host = {e.name for p in ProfileData.from_file(path).planes
+                if p.name.startswith("/host:")
+                for ln in p.lines for e in ln.events}
+        assert "obs.test_span" in host
+
+    def test_disabled_span_opens_no_annotation(self, monkeypatch):
+        opened = []
+
+        class Fake:
+            def __init__(self, name, **kw):
+                opened.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Fake)
+        with trace.span("off.span"):
+            pass
+        assert opened == []
+        trace.enable()
+        with trace.span("on.span"):
+            pass
+        assert opened == ["on.span"]
+
 
 class TestExport:
     def _events(self):
@@ -101,17 +141,6 @@ class TestExport:
         rows = [json.loads(x) for x in open(p)]
         assert [r["ph"] for r in rows] == ["X", "C", "i"]
         assert set(rows[0]) == set(EVENT_SCHEMA)
-
-    def test_chrome_trace_phase_fields(self, tmp_path):
-        p = str(tmp_path / "t.json")
-        assert to_chrome_trace(self._events(), p) == 3
-        doc = json.load(open(p))
-        x, c, i = doc["traceEvents"]
-        assert "dur" in x and x["ph"] == "X"
-        assert i["s"] == "t" and i["ph"] == "i"
-        # counter args must be numeric-or-stringified for the viewer
-        assert all(isinstance(v, (int, float, str))
-                   for v in c["args"].values())
 
     def test_validator_rejects_bad_events(self):
         ok = {"name": "n", "cat": "c", "ph": "i", "ts_us": 1.0,
