@@ -1,7 +1,10 @@
 """GQA attention: full / sliding-window / logit-softcap variants.
 
 Three entry points per layer:
-  * ``attn_train``   — full-sequence causal self-attention (training/prefill)
+  * ``attn_train``   — full-sequence causal self-attention (training/prefill);
+    plain causal attention on the TPU runs the blocked Pallas kernel
+    (``kernels/flash_attn.py``), every other case the dense path
+    (:func:`attn_route` decides)
   * ``attn_prefill`` — attn_train + returns the filled KV cache
   * ``attn_decode``  — one new token against a KV cache (full or ring buffer)
 
@@ -17,8 +20,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import DTYPE, apply_rope, dense_init, softcap
-from repro.obs.trace import scope
+from repro.kernels.flash_attn import flash_attention, flash_block
+from repro.models.common import (DTYPE, apply_rope, dense_init, rope_cos_sin,
+                                 rope_rotate, softcap)
+from repro.obs.trace import instant, scope
 from repro.sharding.ctx import constrain
 
 
@@ -86,6 +91,50 @@ def _causal_mask(s: int, window: Optional[int], positions) -> jnp.ndarray:
     return m[None]
 
 
+def attn_route(s: int, hd: int, window, attn_softcap, pad_mask,
+               positions) -> str:
+    """Which path :func:`attn_train` takes: ``"splash"`` (the blocked
+    Pallas kernel) or ``"dense"`` (materialised scores, :func:`_sdpa`).
+    The kernel covers plain causal attention over ``arange(s)`` on the
+    TPU, at a length and head width it takes (``flash_block``); a window,
+    a logit softcap, a pad mask, explicit positions or another backend
+    stay dense."""
+    if jax.default_backend() != "tpu":
+        return "dense"
+    if window is not None or attn_softcap is not None:
+        return "dense"
+    if pad_mask is not None or positions is not None:
+        return "dense"
+    return "dense" if flash_block(s, hd) is None else "splash"
+
+
+def _attn_splash(params, x, *, num_heads, num_kv_heads, head_dim, pos_embed,
+                 rope_theta):
+    """:func:`attn_train` through the kernel.  The projections emit its
+    ``(B, KV, G, S, hd)`` layout and the output projection reads it back,
+    so no transpose of the heads stands alone; ``1/sqrt(hd)`` is folded
+    into q in f32, with RoPE (``rope_rotate``, whole heads in the minor
+    dimension) where there is RoPE."""
+    b, s, d = x.shape
+    g = num_heads // num_kv_heads
+    q = jnp.einsum("bsd,dkgh->bkgsh", x,
+                   params["wq"].reshape(d, num_kv_heads, g, head_dim))
+    k = jnp.einsum("bsd,dkh->bksh", x,
+                   params["wk"].reshape(d, num_kv_heads, head_dim))
+    v = jnp.einsum("bsd,dkh->bksh", x,
+                   params["wv"].reshape(d, num_kv_heads, head_dim))
+    scale = 1.0 / head_dim ** 0.5
+    if pos_embed == "rope":
+        cos, sin = rope_cos_sin(jnp.arange(s), head_dim, rope_theta)
+        q = rope_rotate(q, cos, sin, scale)
+        k = rope_rotate(k, cos, sin)
+    else:
+        q = (q.astype(jnp.float32) * jnp.float32(scale)).astype(q.dtype)
+    out = flash_attention(q, k, v)
+    return jnp.einsum("bkgsh,kghe->bse", out,
+                      params["wo"].reshape(num_kv_heads, g, head_dim, d))
+
+
 @scope("attn")
 def attn_train(params, x, *, num_heads, num_kv_heads, head_dim,
                pos_embed="rope", rope_theta=10_000.0, window=None,
@@ -95,6 +144,13 @@ def attn_train(params, x, *, num_heads, num_kv_heads, head_dim,
     RoPE logits depend only on position differences, so masking alone
     makes a padded prompt exactly equal to the same prompt unpadded)."""
     b, s, d = x.shape
+    route = attn_route(s, head_dim, window, attn_softcap, pad_mask, positions)
+    instant("attn.route", cat="attn", route=route, s=s, hd=head_dim,
+            g=num_heads // num_kv_heads)
+    if route == "splash":
+        return _attn_splash(params, x, num_heads=num_heads,
+                            num_kv_heads=num_kv_heads, head_dim=head_dim,
+                            pos_embed=pos_embed, rope_theta=rope_theta)
     if positions is None:
         positions = jnp.arange(s)
     q, k, v = _project_qkv(params, x, num_heads, num_kv_heads, head_dim)

@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.obs.trace import scope
 
@@ -88,15 +89,48 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float):
+    """cos and sin of the rotation angles, each (..., S, hd/2) f32."""
+    freqs = rope_freqs(head_dim, theta)                          # (hd/2,)
+    angles = positions[..., None].astype(jnp.float32) * freqs    # (..., S, hd/2)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
-    hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                               # (hd/2,)
-    angles = positions[..., None].astype(jnp.float32) * freqs    # (..., S, hd/2)
-    cos = jnp.cos(angles)[..., None, :]                          # (..., S, 1, hd/2)
-    sin = jnp.sin(angles)[..., None, :]
+    cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
+    cos = cos[..., None, :]                                      # (..., S, 1, hd/2)
+    sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
+def rope_rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+                scale: Optional[float] = None) -> jnp.ndarray:
+    """:func:`apply_rope`'s rotation of a bf16 ``x`` (..., hd) by tables
+    (cos, sin) that broadcast against (..., hd/2), in f32, times
+    ``scale``.  The half swap ``(-x2, x1)`` is a matmul by a signed
+    permutation (exact: each output is one input), so every op keeps the
+    whole head in the minor dimension; on the TPU the 32-lane halves of a
+    64-wide head that :func:`apply_rope` splits cost a relayout each.
+    Op by op the two agree bitwise, but fused under ``jit`` XLA rounds
+    them differently (one bf16 ulp at most for bf16 ``x`` on the CPU), so
+    the dense callers keep :func:`apply_rope` and the numbers their
+    tests pin."""
+    hd = x.shape[-1]
+    h = hd // 2
+    swap = np.zeros((hd, hd), np.float32)
+    swap[np.arange(h) + h, np.arange(h)] = -1.0                  # -> -x2
+    swap[np.arange(h), np.arange(h) + h] = 1.0                   # -> x1
+    # one MXU pass is exact for bf16; f32 needs all of its passes
+    prec = None if x.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    swapped = jnp.einsum("...h,hj->...j", x, jnp.asarray(swap, x.dtype),
+                         precision=prec, preferred_element_type=jnp.float32)
+    out = (x.astype(jnp.float32) * jnp.concatenate([cos, cos], axis=-1)
+           + swapped * jnp.concatenate([sin, sin], axis=-1))
+    if scale is not None:
+        out = out * jnp.float32(scale)
     return out.astype(x.dtype)
 
 

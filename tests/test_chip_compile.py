@@ -1,5 +1,6 @@
-"""Compile rehearsals for a described TPU v5e: the wire kernels and the
-full-width gpt2-small train step, at the shapes the main path gives them.
+"""Compile rehearsals for a described TPU v5e: the wire kernels, the
+causal attention kernel and the full-width gpt2-small train step, at the
+shapes the main path gives them.
 
 Nothing runs: each test compiles for a chip that is described, not
 attached, so the TPU compiler (Mosaic for the kernels) refuses here what
@@ -150,6 +151,14 @@ def _train_step_args(sharding, cfg, opt, policy, transport, batch):
     return (*state, {"tokens": tokens}, ids)
 
 
+def _assert_splash(compiled):
+    """Causal attention ran through the kernel, both ways: its forward,
+    and its backward (one kernel for dq, dk and dv)."""
+    text = compiled.as_text()
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv"):
+        assert kernel in text, kernel
+
+
 def _fits_v5e(compiled):
     mem = compiled.memory_analysis()
     return mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
@@ -171,6 +180,7 @@ def test_gpt2_small_train_step_compiles(one_chip, monkeypatch):
     compiled = step.lower(*_train_step_args(
         one_chip, cfg, opt, policy, "simulated", 8)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_splash(compiled)
     assert _fits_v5e(compiled)
 
 
@@ -199,4 +209,28 @@ def test_gpt2_small_mesh_step_compiles(topo, monkeypatch):
         NamedSharding(mesh, PartitionSpec()), cfg, opt, policy, transport,
         16)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_splash(compiled)
+    assert _fits_v5e(compiled)
+
+
+def test_starcoder2_attention_compiles(one_chip, monkeypatch):
+    """One StarCoder2-7B attention layer (d 4608, 36 query heads over 4
+    KV heads of 128) at 1 x 4096 tokens, forward and backward, on the
+    kernel's route."""
+    from repro.models import attention as A
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, h, kv, hd = 4608, 36, 4, 128
+    kw = dict(num_heads=h, num_kv_heads=kv, head_dim=hd)
+    assert A.attn_route(4096, hd, None, None, None, None) == "splash"
+    params = jax.eval_shape(lambda: A.attn_init(jax.random.PRNGKey(0), d,
+                                                h, kv, hd))
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          params)
+
+    def loss(p, x):
+        return jnp.sum(A.attn_train(p, x, **kw).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        params, _spec(one_chip, (1, 4096, d), jnp.bfloat16)).compile()
+    _assert_splash(compiled)
     assert _fits_v5e(compiled)
