@@ -101,6 +101,7 @@ def quantize_wire(x: jnp.ndarray, bits: int, *, block=(256, 256),
         out_specs=(pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
                    pl.BlockSpec((gm, 2 * gn), lambda i, j: (0, 0))),
         interpret=interpret,
+        name="quantize_wire",
     )(x)
     return codes, meta
 

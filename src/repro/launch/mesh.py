@@ -104,3 +104,45 @@ def make_3d_mesh(dp: int, stages: int, tp: int, *, data_axis: str = "data",
             f"--xla_force_host_platform_device_count={need} before jax init")
     return make_mesh((dp, stages, tp), (data_axis, stage_axis, tensor_axis))
 
+
+_VOCAB_LEAVES = ("embed", "lm_head")
+
+
+def train_state_shardings(tree, mesh, *, stage_axis: str = "stage",
+                          tensor_axis: str = "tensor"):
+    """NamedShardings for an LM's parameters, or for any tree that holds
+    them under the same keys (the optimizer's moments), on the pipeline
+    mesh of :func:`make_3d_mesh`.
+
+    * a ``layers`` leaf ``(L, ...)`` splits its layer dim over
+      ``stage_axis`` (stage s holds the contiguous block of layers that
+      ``transformer.stack_layer_stages`` gives it) and its
+      tensor-parallel dim over ``tensor_axis``
+      (``transformer.tp_param_dims``: attention heads and the MLP width);
+    * the vocabulary matrices (``embed``, and ``lm_head`` when untied)
+      split their rows over both axes, so every chip holds
+      ``1 / (stages * tp)`` of the vocabulary (replicated where the
+      rows do not divide evenly);
+    * everything else (norm scales, the step count) is replicated.
+
+    The train step of ``make_lm_train_step(..., parallel=)`` returns its
+    state in the same layout, so donated state stays in place."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.models.transformer import tp_param_dims
+
+    def spec(path, leaf, tp_dim):
+        keys = [str(getattr(k, "key", k)) for k in path]
+        entries = [None] * leaf.ndim
+        if "layers" in keys:
+            entries[0] = stage_axis
+            if tp_dim >= 0:
+                entries[tp_dim] = tensor_axis
+        elif (keys and keys[-1] in _VOCAB_LEAVES
+              and leaf.shape[0] % (mesh.shape[stage_axis]
+                                   * mesh.shape[tensor_axis]) == 0):
+            entries[0] = (stage_axis, tensor_axis)
+        while entries and entries[-1] is None:   # the step's own spelling
+            entries.pop()
+        return NamedSharding(mesh, P(*entries))
+
+    return jax.tree_util.tree_map_with_path(spec, tree, tp_param_dims(tree))

@@ -179,7 +179,10 @@ def scope(name: str):
     metadata of every HLO op the function emits, in the forward pass, its
     transpose and remat's recompute alike; it adds no op.  The names in
     use (bench/scopes.py reads them): ``vocab``, ``layers``, ``attn``,
-    ``mlp``, ``norm``, ``codec``, ``optimizer``."""
+    ``mlp``, ``norm``, ``codec``, ``optimizer``; and one per ring of the
+    mesh, ``stage_wire`` (a pipeline stage hop, transport/pipeline.py)
+    and ``tensor_wire`` (a tensor-parallel gather or scatter,
+    transport/tp_collectives.py)."""
     def wrap(fn):
         @functools.wraps(fn)
         def scoped(*args, **kwargs):

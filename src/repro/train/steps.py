@@ -295,7 +295,7 @@ def make_lm_train_step(cfg, policy: CompressionPolicy,
                 "1f1b schedule keeps the stash at the boundary tensors)")
         return _make_pipeline_lm_train_step(
             cfg, policy, opt, mesh=mesh, stage_axis=stage_axis,
-            microbatches=pipeline_microbatches, jit=jit,
+            microbatches=pipeline_microbatches, jit=jit, donate=donate,
             schedule=schedule, virtual_stages=virtual_stages,
             dp=dp, dp_codec=dp_codec, dp_feedback=dp_feedback,
             dp_k_frac=dp_k_frac, data_axis=data_axis, tp=tp,
@@ -526,7 +526,8 @@ def _make_pipeline_lm_train_step(cfg, policy: CompressionPolicy,
                                  opt: OptimizerConfig, *, mesh=None,
                                  stage_axis: str = "stage",
                                  microbatches: Optional[int] = None,
-                                 jit: bool = True, schedule: str = "gpipe",
+                                 jit: bool = True, donate: bool = True,
+                                 schedule: str = "gpipe",
                                  virtual_stages: int = 1, dp: int = 1,
                                  dp_codec: str = "none",
                                  dp_feedback: str = "none",
@@ -627,7 +628,70 @@ def _make_pipeline_lm_train_step(cfg, policy: CompressionPolicy,
         return params, opt_state, {"fw": new_fw, "bw": new_bw}, metrics
 
     step = step_feedback if needs_state else step
-    return jax.jit(step) if jit else step
+    if not jit:
+        return step
+    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+
+
+def mesh_wire_bytes(cfg, parallel: ParallelSpec, batch: int, seq: int, *,
+                    microbatches: Optional[int] = None,
+                    remat: bool = True) -> dict:
+    """What one device puts on each ring of the pipeline mesh in one step
+    of :func:`make_lm_train_step` with ``parallel=`` (GPipe, its default
+    schedule): per ring (``"stage"``, ``"tensor"``) the hops it sends,
+    the exact payload bytes of one hop (the codec registry's packed
+    shapes: ``PipelineTransport``'s payload structs and
+    ``tp_wire_report``), their product ``bytes``, the elements one hop
+    encodes and the route that packs them (``transport.codecs.
+    wire_route``).  Pure host arithmetic.
+
+    Stage ring: every tick of the schedule scan sends one forward and one
+    backward hop (the fill and drain ticks and the wrap-around hop
+    included).  Tensor ring: each block gathers and scatters twice
+    forward (attention, MLP) and twice backward; with ``remat`` the
+    recomputed forward sends all of its collectives again except the
+    group's last scatter, whose result the backward does not read.  Each
+    collective is ``tp - 1`` hops of one sequence shard."""
+    from repro.core.policy import BoundaryPolicy
+    from repro.models.common import DTYPE
+    from repro.transport.codecs import codec_for, wire_route
+    from repro.transport.pipeline import PipelineTransport, wire_telemetry
+    from repro.transport.schedules import as_schedule
+    from repro.transport.tp_collectives import tp_wire_report
+    s_stages, tp = parallel.stages, parallel.tp
+    sched = as_schedule("gpipe")
+    mb = microbatches or s_stages
+    mbsz = batch // (mb * parallel.dp)
+    shard = (mbsz, seq // tp, cfg.d_model)
+    n = shard[1] * shard[2]
+    ticks = sched.num_ticks(mb, s_stages)
+    out = {}
+    if s_stages > 1:
+        pol = parallel.stage_policy()
+        bp = BoundaryPolicy() if pol is None else _uniform_boundary(pol)
+        tel = wire_telemetry(PipelineTransport(bp, "stage", s_stages), sched,
+                             shard, DTYPE, microbatches=mb)
+        fw, bw = (tel["fw_payload_bytes_per_hop"],
+                  tel["bw_payload_bytes_per_hop"])
+        out["stage"] = {"hops": 2 * ticks,
+                        "payload_bytes_per_hop": (fw + bw) // 2,
+                        "bytes": ticks * (fw + bw), "elems_per_hop": mbsz * n,
+                        "route": wire_route(codec_for(bp.fw).name,
+                                            (mbsz, n))}
+    if tp > 1:
+        codec = parallel.tensor.codec
+        per_hop = tp_wire_report((mbsz, seq, cfg.d_model), tp, codec,
+                                 k_frac=parallel.tensor.k_frac,
+                                 dtype=DTYPE)["payload_bytes_per_hop"]
+        blocks = len(cfg.layer_kinds())
+        per_group = 8 * blocks + ((4 * blocks - 1) if remat else 0)
+        hops = (ticks * (cfg.num_groups // s_stages) * per_group
+                * (tp - 1))
+        out["tensor"] = {"hops": hops, "payload_bytes_per_hop": per_hop,
+                         "bytes": hops * per_hop, "elems_per_hop": mbsz * n,
+                         # pack_grad_leaf flattens each shard to one row
+                         "route": wire_route(codec, (1, mbsz * n))}
+    return out
 
 
 def _make_dp_pipeline_lm_train_step(cfg, bp, opt: OptimizerConfig, *, mesh,

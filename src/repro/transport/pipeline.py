@@ -69,6 +69,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.feedback import (FeedbackState, gather_rows, get_mode,
                                  needs_recv_mirror, scatter_rows)
 from repro.core.policy import (BoundaryPolicy, quant_policy, topk_policy)
+from repro.obs.trace import scope
 from repro.transport.base import Transport, shard_map_compat as _shard_map
 from repro.transport.codecs import codec_for, fuse_payload, unfuse_payload
 from repro.transport.schedules import Schedule, as_schedule
@@ -323,6 +324,7 @@ class PipelineTransport(Transport):
             ctx = (payload["idx"], moved["idx"])
         return out, fw_buf, ctx
 
+    @scope("stage_wire")
     def fw_hop(self, y, fw_st, meta):
         """Feedback-compensated forward hop inside the pipeline scan.
 
@@ -379,6 +381,7 @@ class PipelineTransport(Transport):
         moved = self._hop(payload, self.perm_bw)
         return self._bw_codec.unpack(moved, g.shape, g.dtype), bw_buf
 
+    @scope("stage_wire")
     def bw_hop(self, g, bw_send_sl, bw_recv_sl, meta, ctx):
         """Feedback-compensated backward hop (runs inside ``send``'s VJP).
 

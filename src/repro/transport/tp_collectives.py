@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.feedback import FEEDBACK_REGISTRY, FeedbackState
+from repro.obs.trace import scope
 from repro.transport.base import shard_map_compat
 from repro.transport.codecs import (
     fuse_payload,
@@ -193,6 +194,7 @@ class TPCollectives:
             return unfuse_payload(slots[s], struct)
         return jax.tree.map(lambda a: a[s], slots)
 
+    @scope("tensor_wire")
     def all_gather_wire(self, x_shard):
         """Ring all-gather of packed shards; every rank decodes all ``tp``
         payloads and concatenates in source-rank order (bitwise identical
@@ -211,6 +213,7 @@ class TPCollectives:
         ]
         return jnp.concatenate(parts, axis=self.seq_dim)
 
+    @scope("tensor_wire")
     def reduce_scatter_wire(self, partial):
         """Packed-slice exchange + source-rank-ordered sum: rank ``r``
         keeps ``sum_s C(partial_s[slice r])``.  Every contribution —

@@ -234,3 +234,51 @@ def test_starcoder2_attention_compiles(one_chip, monkeypatch):
         params, _spec(one_chip, (1, 4096, d), jnp.bfloat16)).compile()
     _assert_splash(compiled)
     assert _fits_v5e(compiled)
+
+
+def test_starcoder2_8l_mesh_step_compiles(topo, monkeypatch):
+    """StarCoder2-7B at its published widths, 8 layers, on the 2-stage x
+    2-tensor mesh with q8 on both wires: batch 16 x 1024 as two 8-row
+    GPipe microbatches, donated state placed by
+    ``launch.mesh.train_state_shardings``.  Each chip's share must fit its
+    16 GiB, the state must come back in the layout it went in (so the
+    donation takes), and the splash and q8 wire kernels must be in the
+    program."""
+    import dataclasses
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.configs.registry import get
+    from repro.core.parallel import spec_from_cli
+    from repro.core.policy import NO_POLICY
+    from repro.launch.mesh import train_state_shardings
+    from repro.launch.train import adamw_config
+    from repro.models import transformer
+    from repro.optim.optimizers import init_opt_state
+    from repro.train.steps import make_lm_train_step
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get("starcoder2-7b"), num_layers=8)
+    opt = adamw_config(1e-3, 3)
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 2, 2),
+                ("data", "stage", "tensor"))
+    spec = spec_from_cli("stage=2,tensor=2", "stage=q8,tensor=q8")
+    step = make_lm_train_step(cfg, NO_POLICY, opt, remat=True, donate=True,
+                              parallel=spec, mesh=mesh,
+                              pipeline_microbatches=2)
+    params = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))
+    state = (params, jax.eval_shape(lambda p: init_opt_state(opt, p),
+                                    params))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        state, train_state_shardings(state, mesh))
+    rep = NamedSharding(mesh, PartitionSpec())
+    compiled = step.lower(
+        *state, [], {"tokens": jax.ShapeDtypeStruct((16, 1024), jnp.int32,
+                                                    sharding=rep)},
+        jax.ShapeDtypeStruct((16,), jnp.int32, sharding=rep)).compile()
+    _assert_splash(compiled)
+    assert "quantize_wire" in compiled.as_text()
+    assert _fits_v5e(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    went_in = [a.sharding for a in jax.tree.leaves(state)]
+    came_out = jax.tree.leaves(compiled.output_shardings[:2])
+    assert [s.spec for s in came_out] == [s.spec for s in went_in]
