@@ -174,7 +174,8 @@ def measure_framing(shape=SHAPE):
 
 def measure_dp_decode(dp=4, leaf_shapes=((128, 129), (2048,), (33,))):
     """Fused decode+sum kernel vs the unfused unpack->add reference loop,
-    on manually stacked hop buffers (no mesh needed)."""
+    on hop buffers laid out as rank 0 of the ring holds them (no mesh
+    needed)."""
     from repro.kernels.dp_reduce import (build_decode_plans, decode_fits,
                                          decode_sum_fused)
     from repro.transport.collectives import pack_grad_leaf, unpack_grad_leaf
@@ -187,32 +188,32 @@ def measure_dp_decode(dp=4, leaf_shapes=((128, 129), (2048,), (33,))):
             leaves = [jax.random.normal(jax.random.PRNGKey(7 * s + i), sh)
                       for i, sh in enumerate(leaf_shapes)]
             per_src.append([pack_grad_leaf(codec, a) for a in leaves])
-        slots = jnp.stack([_on_backend("jnp", codecs.fuse_payload, p)
-                           for p in per_src])
+        bufs = [_on_backend("jnp", codecs.fuse_payload, p) for p in per_src]
+        hops = [bufs[-h % dp] for h in range(dp)]
         struct = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), per_src[0])
         plans = build_decode_plans(struct, list(leaf_shapes))
         assert plans is not None and decode_fits(plans, dp), codec_name
 
-        def reference(sl):
+        def reference(hs):
             acc = [None] * len(leaf_shapes)
             for s in range(dp):
-                pls = codecs.unfuse_payload(sl[s], struct)
+                pls = codecs.unfuse_payload(hs[-s % dp], struct)
                 for i, sh in enumerate(leaf_shapes):
                     m = unpack_grad_leaf(codec, pls[i], sh)
                     acc[i] = m if acc[i] is None else acc[i] + m
             return acc
 
-        def ref_fn(sl):
-            return _on_backend("jnp", reference, sl)
+        def ref_fn(hs):
+            return _on_backend("jnp", reference, hs)
 
-        def fused_fn(sl):
-            return decode_sum_fused(sl, plans, dp)
+        def fused_fn(hs):
+            return decode_sum_fused(hs, plans, jnp.int32(0))
 
-        t_ref = _timeit(jax.jit(ref_fn), slots)
-        t_fused = _timeit(jax.jit(fused_fn), slots)
-        want = ref_fn(slots)
-        got = fused_fn(slots)
+        t_ref = _timeit(jax.jit(ref_fn), hops)
+        t_fused = _timeit(jax.jit(fused_fn), hops)
+        want = ref_fn(hops)
+        got = fused_fn(hops)
         ok = all(
             np.allclose(np.asarray(g).reshape(-1), np.asarray(w).reshape(-1),
                         rtol=0,
@@ -226,7 +227,7 @@ def measure_dp_decode(dp=4, leaf_shapes=((128, 129), (2048,), (33,))):
         rows.append({
             "name": f"dp_decode_sum:{codec_name}", "codec": codec_name,
             "dp": dp, "leaves": len(leaf_shapes),
-            "hop_buffer_bytes": int(slots.shape[1]),
+            "hop_buffer_bytes": int(hops[0].shape[0]),
             "dense_bytes": dense_bytes,
             "jnp_gbps": _gbps(dense_bytes, t_ref),
             "pallas_gbps": _gbps(dense_bytes, t_fused),

@@ -1,21 +1,22 @@
 """Pallas TPU kernel: fused receive-side decode+sum for the DP ring.
 
 After the ``ppermute`` ring (transport/collectives.py) every replica holds
-``slots`` — the fused uint8 hop buffers of all ``dp`` source ranks, stacked
-in SOURCE-RANK order.  The jnp path then runs, per source rank and per
-parameter leaf, an unfuse slice + bitcast + dequantize + add: O(dp * leaves)
-kernel launches and a dense f32 HBM round-trip per step, on the receive path
-of every ring hop.  The kernel here does the whole thing in one launch: for
-each leaf it walks the ``dp`` byte segments at their static offsets,
-decodes the uint8 codes in-register (q8 bytes or q4 nibble pairs, the same
-``codes * scale + min`` dequant as ``dequantize_kbit``) and accumulates in
-a STATIC source-rank-ordered fold.  The fold association is fixed and every
-replica executes the identical program, so all replicas still compute a
-bitwise-identical reduced gradient — the DP acceptance invariant, asserted
-in tests/test_codec_kernels.py.  Against the unfused XLA reference loop the
-dequant may differ by at most 1 ulp where the compiler contracts the
-multiply-add into an FMA (a strictly-more-precise rounding; the tests pin
-this bound).
+``hops`` — the fused uint8 buffers of all ``dp`` source ranks, in HOP order
+(hop ``h`` came from source ``(r - h) % dp``).  The jnp path then runs, per
+hop and per parameter leaf, an unfuse slice + bitcast + dequantize, and a
+source-ordered add: O(dp * leaves) kernel launches and a dense f32 HBM
+round-trip per step, on the receive path of every ring hop.  The kernel
+here does the whole thing in one launch: for each leaf it walks the ``dp``
+byte segments at their static offsets, decodes the uint8 codes in-register
+(q8 bytes or q4 nibble pairs, the same ``codes * scale + min`` dequant as
+``dequantize_kbit``), picks each source's decoded value by a select on the
+rank and accumulates in a STATIC source-rank-ordered fold.  The fold
+association is fixed and every replica executes the identical program, so
+all replicas still compute a bitwise-identical reduced gradient — the DP
+acceptance invariant, asserted in tests/test_codec_kernels.py.  Against
+the unfused XLA reference loop the dequant may differ by at most 1 ulp
+where the compiler contracts the multiply-add into an FMA (a
+strictly-more-precise rounding; the tests pin this bound).
 
 The per-source per-leaf (min, scale) f32 scalars are extracted from the
 buffer bytes by XLA bitcasts beforehand (Mosaic has no size-changing
@@ -34,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# slots + meta + f32 accumulators all resident at once.
+# hop buffers + meta + f32 accumulators and one leaf decoded by hop, all
+# resident at once.
 DECODE_MAX_BYTES = 4 * 1024 * 1024
 
 _Q8_KEYS = frozenset(("codes", "min", "scale"))
@@ -92,34 +94,43 @@ def build_decode_plans(structs, leaf_shapes) -> Optional[List[LeafPlan]]:
     return plans
 
 
-def extract_meta(slots: jnp.ndarray, plans: Sequence[LeafPlan]):
-    """(dp, nbytes) uint8 slots -> (dp, 2 * leaves) f32 of per-source
-    (min, scale) pairs, bitcast straight from the payload bytes."""
-    dp = slots.shape[0]
-    cols = []
-    for p in plans:
-        for o in (p.meta_off, p.meta_off + 4):
-            cols.append(jax.lax.bitcast_convert_type(
-                slots[:, o:o + 4], jnp.float32))
-    return jnp.stack(cols, axis=1).reshape(dp, 2 * len(plans))
+def extract_meta(hops: Sequence[jnp.ndarray], plans: Sequence[LeafPlan]):
+    """``dp`` (nbytes,) uint8 hop buffers -> (dp, 2 * leaves) f32 of
+    per-hop (min, scale) pairs, bitcast straight from the payload bytes."""
+    rows = []
+    for b in hops:
+        cols = []
+        for p in plans:
+            for o in (p.meta_off, p.meta_off + 4):
+                cols.append(jax.lax.bitcast_convert_type(b[o:o + 4],
+                                                         jnp.float32))
+        rows.append(jnp.stack(cols))
+    return jnp.stack(rows)
 
 
-def _decode_sum_kernel(slots_ref, meta_ref, *o_refs,
+def _decode_sum_kernel(order_ref, meta_ref, *refs,
                        plans: Sequence[LeafPlan], dp: int):
     """One output per q8 leaf; two (even and odd nibble planes) per q4
     leaf — Mosaic has no lane interleave, so the planes are interleaved
     once, after the fold, in XLA.  uint8 widens through int32 (Mosaic has
-    no uint8->float cast)."""
-    outs = iter(o_refs)
+    no uint8->float cast).  ``order_ref[0, s]`` is the hop that holds
+    source ``s``; the select compares it as a vector (a lane mask)."""
+    hop_refs, outs = refs[:dp], iter(refs[dp:])
     for li, p in enumerate(plans):
+        by_hop = []
+        for h in range(dp):
+            seg = hop_refs[h][:, p.off:p.off + p.nbytes].astype(jnp.int32)
+            mn = meta_ref[h, 2 * li]
+            sc = meta_ref[h, 2 * li + 1]
+            planes = ((seg,) if p.kind == "q8" else (seg & 0xF, seg >> 4))
+            by_hop.append([c.astype(jnp.float32) * sc + mn for c in planes])
         accs = None
         for s in range(dp):                  # static rank-ordered fold
-            seg = slots_ref[s:s + 1, p.off:p.off + p.nbytes].astype(
-                jnp.int32)
-            mn = meta_ref[s, 2 * li]
-            sc = meta_ref[s, 2 * li + 1]
-            planes = ((seg,) if p.kind == "q8" else (seg & 0xF, seg >> 4))
-            ds = [c.astype(jnp.float32) * sc + mn for c in planes]
+            which = jnp.full(by_hop[0][0].shape, order_ref[0, s], jnp.int32)
+            ds = by_hop[0]
+            for h in range(1, dp):
+                ds = [jnp.where(which == h, d, a)
+                      for a, d in zip(ds, by_hop[h])]
             accs = ds if accs is None else [a + d for a, d in zip(accs, ds)]
         for a in accs:
             next(outs)[...] = a
@@ -129,29 +140,33 @@ def decode_fits(plans: Sequence[LeafPlan], dp: int,
                 budget: int = DECODE_MAX_BYTES) -> bool:
     nbytes = plans[-1].meta_off + 8 if plans else 0
     dense = sum(p.n for p in plans) * 4
-    return dp * nbytes + dense + dp * len(plans) * 8 <= budget
+    by_hop = dp * max((p.n for p in plans), default=0) * 4
+    return dp * nbytes + dense + by_hop + dp * len(plans) * 8 <= budget
 
 
-def decode_sum_fused(slots: jnp.ndarray, plans: Sequence[LeafPlan],
-                     dp: int, *,
+def decode_sum_fused(hops: Sequence[jnp.ndarray], plans: Sequence[LeafPlan],
+                     rank, *,
                      interpret: bool | None = None) -> List[jnp.ndarray]:
-    """slots: (dp, nbytes) uint8 source-rank-ordered hop buffers.  Returns
-    one (1, n) float32 rank-summed dense gradient per leaf plan — the same
-    static rank-ordered association as the unfuse->dequantize->add
-    reference loop (identical on every replica; <= 1 ulp of FMA rounding
-    vs the unfused loop)."""
-    assert slots.ndim == 2 and slots.dtype == jnp.uint8, (
-        slots.shape, slots.dtype)
-    assert slots.shape[0] == dp, (slots.shape, dp)
+    """hops: the ``dp`` (nbytes,) uint8 buffers of the ring in hop order
+    (:func:`repro.transport.collectives.ring_exchange`: hop ``h`` holds
+    source ``(rank - h) % dp``); ``rank``: this replica's index on the
+    ring (traced).  Returns one (1, n) float32 rank-summed dense gradient
+    per leaf plan — the same static source-rank-ordered association as
+    the unfuse->dequantize->add reference loop (identical on every
+    replica; <= 1 ulp of FMA rounding vs the unfused loop)."""
+    dp = len(hops)
+    assert all(b.ndim == 1 and b.dtype == jnp.uint8 for b in hops), [
+        (b.shape, b.dtype) for b in hops]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    meta = extract_meta(slots, plans)
+    meta = extract_meta(hops, plans)
+    order = ((rank - jnp.arange(dp, dtype=jnp.int32)) % dp).reshape(1, dp)
     out = iter(pl.pallas_call(
         functools.partial(_decode_sum_kernel, plans=tuple(plans), dp=dp),
         out_shape=[jax.ShapeDtypeStruct((1, p.nbytes), jnp.float32)
                    for p in plans for _ in range(1 if p.kind == "q8" else 2)],
         interpret=interpret,
-    )(slots, meta))
+    )(order, meta, *[b.reshape(1, -1) for b in hops]))
     dense = []
     for p in plans:
         if p.kind == "q8":

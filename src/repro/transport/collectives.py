@@ -18,10 +18,14 @@ Scheme (the standard compress-then-exchange all-reduce, cf. Agarwal et al.,
      1F1B fused-hop trick — one collective launch per ring hop instead of
      one per payload leaf);
   3. the buffers ride a ``ppermute`` ring over the data axis (``dp - 1``
-     hops), each replica banking the in-flight buffer by SOURCE RANK;
-  4. every replica decodes the ``dp`` payloads and sums them in source-rank
-     order — a fixed association, so all replicas compute a bitwise
-     identical reduced gradient (ring-order sums would diverge per rank).
+     hops), each replica keeping the received buffers in a list by hop
+     (:func:`ring_exchange`; the hop is static, so no buffer is written
+     or read at a traced position);
+  4. every replica decodes each hop's payload and sums the ``dp`` decoded
+     values in source-rank order, choosing each source's hop with a select
+     fused into the decode (:func:`by_source`) — a fixed association, so
+     all replicas compute a bitwise identical reduced gradient (ring-order
+     sums would diverge per rank).
 
 ``codec="none"`` is a RAW passthrough (native dtype, no bf16 downcast), so
 an uncompressed DP reduce is bit-exact against serial gradient summation —
@@ -147,26 +151,42 @@ def init_dp_state(grads_like, dp: int, feedback: str = "none",
                          direction="grad", mode=feedback)
 
 
-def _ring_gather(payload_tree, axis: str, dp: int):
-    """All-gather via a ``ppermute`` ring: ``dp - 1`` hops, banking the
-    in-flight payload by SOURCE rank.  Returns the payload pytree with a
-    leading ``(dp,)`` dim ordered by source rank (identical on every
-    replica up to its own shard's position — the decode sums in rank
-    order, so the reduction is association-fixed)."""
+def ring_exchange(axis: str, n: int, own, send_at=None) -> List:
+    """Exchange payloads around a ``ppermute`` ring of ``n`` ranks.
+
+    Returns ``[own, hop_1, ..., hop_{n-1}]``: hop ``h`` holds the payload
+    of source rank ``(r - h) % n``, each entry its own array straight out
+    of ``ppermute`` — the list is indexed by hop, which is static, so no
+    buffer is ever written or read at a position computed from the traced
+    rank.  :func:`by_source` puts decoded values in source-rank order.
+
+    ``send_at=None`` is the all-gather: ``own`` travels the ring one rank
+    a hop.  ``send_at(h)`` is the reduce-scatter: at hop ``h`` this rank
+    sends ``send_at(h)`` straight to rank ``(r + h) % n``.
+    """
+    hops = [own]
+    for h in range(1, n):
+        if send_at is None:
+            perm = [(i, (i + 1) % n) for i in range(n)]
+            hops.append(jax.lax.ppermute(hops[-1], axis, perm))
+        else:
+            perm = [(i, (i + h) % n) for i in range(n)]
+            hops.append(jax.lax.ppermute(send_at(h), axis, perm))
+    return hops
+
+
+def by_source(by_hop: List, axis: str) -> List:
+    """Per-hop values (pytrees) from :func:`ring_exchange` -> the same
+    values in source-rank order.  Entry ``s`` selects hop ``(r - s) % n``
+    with ``lax.select_n`` on the decoded values, an elementwise op that
+    XLA fuses into the decode; summing the entries in list order gives
+    every rank the same association, so the same bits."""
+    n = len(by_hop)
+    if n == 1:
+        return list(by_hop)
     r = jax.lax.axis_index(axis)
-    slots = jax.tree.map(
-        lambda a: jnp.zeros((dp, *a.shape), a.dtype).at[r].set(a),
-        payload_tree)
-    if dp == 1:
-        return slots
-    perm = [(i, (i + 1) % dp) for i in range(dp)]
-    inflight = payload_tree
-    for h in range(1, dp):
-        inflight = jax.lax.ppermute(inflight, axis, perm)
-        src = (r - h) % dp
-        slots = jax.tree.map(lambda sl, a: sl.at[src].set(a), slots,
-                             inflight)
-    return slots
+    pick = lambda s: lambda *hs: jax.lax.select_n((r - s) % n, *hs)
+    return [jax.tree.map(pick(s), *by_hop) for s in range(n)]
 
 
 def make_grad_all_reduce(mesh: Mesh, axis: str, codec: str = "none", *,
@@ -258,16 +278,12 @@ def make_grad_all_reduce(mesh: Mesh, axis: str, codec: str = "none", *,
         # -- exchange: one fused buffer (or the raw payload pytree) ---------
         struct = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), payloads)
-        if fused:
-            slots = _ring_gather(fuse_payload(payloads), axis, dp)
-            slot = lambda s: unfuse_payload(slots[s], struct)
-        else:
-            slots = _ring_gather(payloads, axis, dp)
-            slot = lambda s: jax.tree.map(lambda a: a[s], slots)
+        hops = ring_exchange(axis, dp,
+                             fuse_payload(payloads) if fused else payloads)
 
-        # -- decode + sum in source-rank order ------------------------------
+        # -- decode by hop + sum in source-rank order -----------------------
         # On the Pallas backend the whole receive side (unfuse -> dequant ->
-        # rank-ordered accumulate) fuses into ONE kernel per hop
+        # rank-ordered accumulate) fuses into ONE kernel
         # (kernels/dp_reduce.py) when every leaf rides the per-tensor
         # q8/q4 wire format.  The fold is static and source-rank ordered
         # and every replica runs the identical program, so the reduced
@@ -283,17 +299,18 @@ def make_grad_all_reduce(mesh: Mesh, axis: str, codec: str = "none", *,
             if plans is not None and not decode_fits(plans, dp):
                 plans = None
         if plans is not None:
-            dense = decode_sum_fused(slots, plans, dp)
+            dense = decode_sum_fused(hops, plans, jax.lax.axis_index(axis))
             acc = [d.reshape(g.shape) for d, g in zip(dense, gl)]
         else:
-            acc = [None] * len(gl)
-            for s in range(dp):
-                pls = slot(s)
-                for i, g in enumerate(gl):
-                    m = unpack_grad_leaf(codec_obj, pls[i], g.shape)
-                    acc[i] = m if acc[i] is None else acc[i] + m
+            received = [payloads] + [unfuse_payload(b, struct) if fused
+                                     else b for b in hops[1:]]
+            decoded = [[unpack_grad_leaf(codec_obj, pls[i], g.shape)
+                        for i, g in enumerate(gl)] for pls in received]
+            acc = None
+            for ms in by_source(decoded, axis):
+                acc = ms if acc is None else [a + m for a, m in zip(acc, ms)]
 
-        # -- feedback state updates (own decode == own slot, same bits) ----
+        # -- feedback state updates (own decode == hop 0's, same bits) -----
         new_rl, new_al, out = [], [], []
         for i, g in enumerate(gl):
             if feedback == "none":

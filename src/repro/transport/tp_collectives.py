@@ -11,13 +11,19 @@ kernels/framing.py):
   * activation path: an ALL-GATHER of the sequence-sharded residual
     before each sharded matmul group — every rank packs its ``(B, S/tp,
     d)`` shard, the payloads ride a ``ppermute`` ring (``tp - 1`` hops),
-    and every rank decodes all ``tp`` payloads in source-rank order, so
-    the gathered activation is bitwise identical on every rank;
+    and every rank decodes the ``tp`` payloads and concatenates them in
+    source-rank order, so the gathered activation is bitwise identical on
+    every rank;
   * gradient path: a REDUCE-SCATTER of the partial outputs / incoming
     activation-gradients — rank ``r`` packs the slice destined for each
     peer and sends it at ring distance ``h`` (``tp - 1`` single-hop
     permutes), then sums the ``tp`` decoded contributions for its own
     slice in source-rank order (fixed association).
+
+Received payloads are kept by hop, a static index, and ordered by source
+rank at decode (``collectives.ring_exchange`` / ``by_source``, shared
+with the DP ring): the rank-dependent choice is a select on the decoded
+values, never a uint8 buffer written or read at a traced position.
 
 Both primitives are differentiable with the straight-through convention
 the pipeline transport uses: the VJP of the compressed all-gather is the
@@ -61,8 +67,9 @@ from repro.transport.codecs import (
 )
 from repro.transport.collectives import (
     _leaf_n,
-    _ring_gather,
+    by_source,
     pack_grad_leaf,
+    ring_exchange,
     unpack_grad_leaf,
 )
 
@@ -189,36 +196,43 @@ class TPCollectives:
         m = unpack_grad_leaf(self._codec, payload, shape)
         return m.astype(dtype)
 
-    def _slot(self, slots, struct, s: int):
-        if self.fused:
-            return unfuse_payload(slots[s], struct)
-        return jax.tree.map(lambda a: a[s], slots)
+    def _ring(self, own, shape, dtype, send_at=None):
+        """Exchange packed payloads round the ring (:func:`ring_exchange`;
+        ``send_at`` as there) -> their decoded values in source-rank
+        order.  Each hop's buffer is unfused and decoded on its own; only
+        the decoded values are put in order (:func:`by_source`, a select
+        that XLA fuses into the decode), so no uint8 buffer is indexed by
+        the traced rank."""
+        struct = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), own)
+        wire = fuse_payload if self.fused else (lambda p: p)
+        hops = ring_exchange(
+            self.axis, self.tp, wire(own),
+            None if send_at is None else lambda h: wire(send_at(h)))
+        received = [own] + [unfuse_payload(b, struct) if self.fused else b
+                            for b in hops[1:]]
+        return by_source([self._decode(p, shape, dtype) for p in received],
+                         self.axis)
 
     @scope("tensor_wire")
     def all_gather_wire(self, x_shard):
-        """Ring all-gather of packed shards; every rank decodes all ``tp``
-        payloads and concatenates in source-rank order (bitwise identical
-        output on every rank)."""
+        """Ring all-gather of packed shards; every rank decodes each hop's
+        payload and concatenates the ``tp`` values in source-rank order
+        (bitwise identical output on every rank)."""
         if self.tp == 1:
             return x_shard
-        payload = self._pack(x_shard)
-        struct = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), payload)
-        wire = fuse_payload(payload) if self.fused else payload
-        slots = _ring_gather(wire, self.axis, self.tp)
-        parts = [
-            self._decode(self._slot(slots, struct, s), x_shard.shape,
-                         x_shard.dtype)
-            for s in range(self.tp)
-        ]
+        parts = self._ring(self._pack(x_shard), x_shard.shape,
+                           x_shard.dtype)
         return jnp.concatenate(parts, axis=self.seq_dim)
 
     @scope("tensor_wire")
     def reduce_scatter_wire(self, partial):
         """Packed-slice exchange + source-rank-ordered sum: rank ``r``
-        keeps ``sum_s C(partial_s[slice r])``.  Every contribution —
-        including the rank's own — goes through the codec, so the sum is
-        uniformly compressed (same convention as the DP reduce)."""
+        keeps ``sum_s C(partial_s[slice r])``.  At hop ``h`` rank ``r``
+        packs the slice of rank ``(r + h) % tp`` and sends it there.
+        Every contribution — including the rank's own — goes through the
+        codec, so the sum is uniformly compressed (same convention as the
+        DP reduce)."""
         tp, dim = self.tp, self.seq_dim
         if tp == 1:
             return partial
@@ -228,35 +242,16 @@ class TPCollectives:
                              f"tp={tp}")
         sl = partial.shape[dim] // tp
         r = jax.lax.axis_index(self.axis)
-        payloads = [
-            self._pack(jax.lax.dynamic_slice_in_dim(partial, j * sl, sl,
-                                                    dim))
-            for j in range(tp)
-        ]
-        struct = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), payloads[0])
-        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *payloads)
-        own = jax.tree.map(lambda a: a[r], stacked)
-        slots = jax.tree.map(
-            lambda a: jnp.zeros((tp, *a.shape), a.dtype).at[r].set(a), own)
-        for h in range(1, tp):
-            dest = (r + h) % tp
-            send = jax.tree.map(lambda a: a[dest], stacked)
-            perm = [(i, (i + h) % tp) for i in range(tp)]
-            if self.fused:
-                buf = jax.lax.ppermute(fuse_payload(send), self.axis, perm)
-                moved = unfuse_payload(buf, struct)
-            else:
-                moved = jax.lax.ppermute(send, self.axis, perm)
-            src = (r - h) % tp
-            slots = jax.tree.map(
-                lambda banked, a: banked.at[src].set(a), slots, moved)
+
+        def pack_for(h):
+            return self._pack(jax.lax.dynamic_slice_in_dim(
+                partial, ((r + h) % tp) * sl, sl, dim))
+
         shard_shape = list(partial.shape)
         shard_shape[dim] = sl
         out = None
-        for s in range(tp):
-            m = self._decode(jax.tree.map(lambda a: a[s], slots),
-                             tuple(shard_shape), partial.dtype)
+        for m in self._ring(pack_for(0), tuple(shard_shape), partial.dtype,
+                            pack_for):
             out = m if out is None else out + m
         return out
 

@@ -127,8 +127,11 @@ def test_decode_sum_fused_compiles(one_chip, codec):
     plans = build_decode_plans(structs, shapes)
     assert plans is not None and decode_fits(plans, 2)
     nbytes = plans[-1].meta_off + 8
-    _compile(lambda s: decode_sum_fused(s, plans, 2, interpret=False),
-             _spec(one_chip, (2, nbytes), jnp.uint8))
+    _compile(lambda a, b, r: decode_sum_fused([a, b], plans, r,
+                                              interpret=False),
+             _spec(one_chip, (nbytes,), jnp.uint8),
+             _spec(one_chip, (nbytes,), jnp.uint8),
+             _spec(one_chip, (), jnp.int32))
 
 
 def _train_step_args(sharding, cfg, opt, policy, transport, batch):

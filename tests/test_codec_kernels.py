@@ -369,7 +369,9 @@ class TestFusedDpDecodeSum:
                 [a.shape for a in jax.tree.leaves(GRADS_LIKE)]) is None
 
     def test_decode_sum_kernel_direct(self, pallas_backend):
-        """Kernel vs hand loop on manually packed slots, incl. odd leaf."""
+        """Kernel vs hand loop on manually packed hop buffers, incl. odd
+        leaf, for every rank of the ring: each rank holds the buffers in
+        its own hop order and must return the source-ordered sum."""
         from repro.kernels.dp_reduce import (build_decode_plans,
                                              decode_sum_fused)
         from repro.transport.collectives import (pack_grad_leaf,
@@ -379,13 +381,19 @@ class TestFusedDpDecodeSum:
         leaves = [jax.random.normal(jax.random.PRNGKey(i), (5, 33))
                   for i in range(dp)]
         payloads = [[pack_grad_leaf(codec, a)] for a in leaves]
-        slots = jnp.stack([codecs.fuse_payload(p) for p in payloads])
+        bufs = [codecs.fuse_payload(p) for p in payloads]
         struct = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), payloads[0])
         plans = build_decode_plans(struct, [(5, 33)])
         assert plans is not None
-        got = decode_sum_fused(slots, plans, dp)[0].reshape(5, 33)
-        want = sum(unpack_grad_leaf(codec, p[0], (5, 33))
-                   for p in payloads)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+        want = None
+        for p in payloads:                   # source-rank order
+            m = unpack_grad_leaf(codec, p[0], (5, 33))
+            want = m if want is None else want + m
+        got = [decode_sum_fused([bufs[(r - h) % dp] for h in range(dp)],
+                                plans, jnp.int32(r))[0].reshape(5, 33)
+               for r in range(dp)]
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                    rtol=0, atol=dp * 1.2e-7 * 10)
+        for g in got[1:]:                    # same bits on every rank
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(got[0]))

@@ -2,12 +2,14 @@
 
 In-process: mesh constructors, TPCollectives/init_tp_state validation,
 the tp=1 degenerate passthrough, and the exact-vs-model wire cost of
-``tp_wire_report`` per codec.  Subprocesses (forced host devices): the
-tp=2 toy acceptance — codec="none" training BIT-IDENTICAL to a blocked
-rank-ordered solo reference, q8+EF tracking it step for step — the LM
-DPxTP step behind ``parallel=ParallelSpec``, and the 8-device 2x2x2
-(data, stage, tensor) pipeline run.
+``tp_wire_report`` per codec.  Subprocesses (forced host devices): rings
+of four against a source-ordered oracle and the lowering gate on payload
+banking, the tp=2 toy acceptance — codec="none" training BIT-IDENTICAL to
+a blocked rank-ordered solo reference, q8+EF tracking it step for step —
+the LM DPxTP step behind ``parallel=ParallelSpec``, and the 8-device
+2x2x2 (data, stage, tensor) pipeline run.
 """
+import json
 import os
 import subprocess
 import sys
@@ -343,6 +345,184 @@ LM_3D_SCRIPT = textwrap.dedent("""
         assert abs(a - b) <= 0.2 * max(abs(b), 1.0), (full, ref)
     print("LM_3D_OK")
 """)
+
+
+# A ring of four: the association of the source-ordered sum matters here
+# (at tp=2 every sum is two terms and commutes).  Each case is checked
+# bitwise on every rank against a plain oracle that packs each source's
+# tensor with the codec and sums the decoded values in source-rank order.
+# The same process lowers the tp=2 q8 gather and reduce-scatter and the
+# dp=2 q8 reduce and looks for banking of uint8 payloads by the traced
+# rank: a scatter, or a dynamic (update-)slice of a ui8 buffer.
+
+RING4_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import re
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+    from repro.transport.base import shard_map_compat
+    from repro.transport.codecs import get_codec
+    from repro.transport.collectives import (init_dp_state,
+                                             make_grad_all_reduce,
+                                             pack_grad_leaf,
+                                             unpack_grad_leaf)
+    from repro.transport.tp_collectives import TPCollectives
+
+    N, B, SL, D = 4, 2, 8, 16
+    results = {}
+
+
+    def check(name, fn):
+        try:
+            fn()
+            results[name] = "ok"
+        except Exception as e:                      # reported per case
+            results[name] = f"{type(e).__name__}: {e}"[:2000]
+
+
+    def roundtrip(codec, a, shape, dtype):
+        # the plain oracle: pack one source's tensor, decode it
+        return unpack_grad_leaf(codec, pack_grad_leaf(codec, a, 0.1),
+                                shape).astype(dtype)
+
+
+    def per_rank(tpc, op):
+        # rank r's own input in, rank r's own output out (no replication)
+        mesh = tpc.mesh
+        return jax.jit(shard_map_compat(lambda a: op(a[0])[None], mesh,
+                                        (P("tensor"),), P("tensor")))
+
+
+    tmesh = make_mesh((N,), ("tensor",))
+    key = jax.random.PRNGKey(0)
+    shards = jax.random.normal(key, (N, B, SL, D)).astype(jnp.bfloat16)
+    partials = jax.random.normal(jax.random.PRNGKey(1),
+                                 (N, B, N * SL, D)).astype(jnp.bfloat16)
+    for codec_name in ("none", "q8", "q4", "topk"):
+        codec = get_codec(codec_name)
+        for fused in (True, False):
+            tag = f"{codec_name}-{'fused' if fused else 'unfused'}"
+            tpc = TPCollectives(tmesh, "tensor", codec=codec_name, fused=fused)
+
+            def gather():
+                got = np.asarray(per_rank(tpc, tpc.all_gather_wire)(shards))
+                want = jax.jit(lambda xs: jnp.concatenate(
+                    [roundtrip(codec, xs[s], xs[s].shape, xs.dtype)
+                     for s in range(N)], axis=1))(shards)
+                for r in range(N):
+                    np.testing.assert_array_equal(got[r], np.asarray(want))
+
+            def scatter():
+                got = np.asarray(
+                    per_rank(tpc, tpc.reduce_scatter_wire)(partials))
+
+                def oracle(ps, r):
+                    out = None
+                    for s in range(N):         # source-rank order
+                        m = roundtrip(codec, ps[s][:, r * SL:(r + 1) * SL],
+                                      (B, SL, D), ps.dtype)
+                        out = m if out is None else out + m
+                    return out
+                for r in range(N):
+                    want = jax.jit(oracle, static_argnums=1)(partials, r)
+                    np.testing.assert_array_equal(got[r], np.asarray(want))
+
+            check(f"gather-{tag}", gather)
+            check(f"scatter-{tag}", scatter)
+
+    dmesh = make_mesh((N,), ("data",))
+    GRADS = {"w": jnp.zeros((4, 33)), "b": jnp.zeros((7,))}
+    steps = [jax.tree.map(lambda a, i=i: jax.random.normal(
+        jax.random.PRNGKey(10 + i), (N, *a.shape)), GRADS) for i in range(2)]
+    codec = get_codec("q8")
+    for fb in ("none", "ef"):
+        def reduce(fb=fb):
+            red = jax.jit(make_grad_all_reduce(dmesh, "data", "q8",
+                                               feedback=fb))
+            state = init_dp_state(GRADS, N, fb)
+            e = {k: [jnp.zeros(a.shape, jnp.float32)] * N
+                 for k, a in GRADS.items()}
+            @jax.jit
+            def oracle(g_dp, e):
+                want, new_e = {}, {}
+                for k, a in GRADS.items():
+                    new_e[k] = []
+                    for s in range(N):         # source-rank order
+                        x = g_dp[k][s].astype(jnp.float32)
+                        if fb == "ef":
+                            x = x + e[k][s]
+                        m = roundtrip(codec, x, a.shape, jnp.float32)
+                        new_e[k].append(x - m)
+                        want[k] = m if k not in want else want[k] + m
+                return want, new_e
+
+            for g_dp in steps:
+                got, state = red(g_dp, state)
+                want, new_e = oracle(g_dp, e)
+                for k in GRADS:
+                    np.testing.assert_array_equal(np.asarray(got[k]),
+                                                  np.asarray(want[k]))
+                    if fb == "ef":
+                        np.testing.assert_array_equal(
+                            np.asarray(state.resid[k]),
+                            np.asarray(jnp.stack(new_e[k])))
+                e = new_e
+        check(f"dp-q8-{fb}", reduce)
+
+    # lowering gate: no scatter, no dynamic (update-)slice of a uint8 buffer
+    banking = re.compile(r"stablehlo\\.scatter"
+                         r"|stablehlo\\.dynamic_(update_)?slice[^\\n]*ui8")
+    mesh2 = make_mesh((2,), ("tensor",))
+    tpc2 = TPCollectives(mesh2, "tensor", codec="q8")
+    dmesh2 = make_mesh((2,), ("data",))
+    red2 = make_grad_all_reduce(dmesh2, "data", "q8")
+    lowered = {
+        "tp-gather": per_rank(tpc2, tpc2.all_gather_wire).lower(shards[:2]),
+        "tp-scatter": per_rank(tpc2, tpc2.reduce_scatter_wire).lower(
+            partials[:2, :, :2 * SL]),
+        "dp-reduce": jax.jit(red2).lower(
+            jax.tree.map(lambda a: a[:2], steps[0]), init_dp_state(GRADS, 2)),
+    }
+    for name, low in lowered.items():
+        hits = [m.group(0)[:120] for m in banking.finditer(low.as_text())]
+        results[f"lower-{name}"] = ("ok" if not hits
+                                    else f"{len(hits)}: {hits[:4]}")
+    print("RING4 " + json.dumps(results))
+""")
+
+RING4_CASES = ([f"{op}-{codec}-{fusion}" for op in ("gather", "scatter")
+                for codec in ("none", "q8", "q4", "topk")
+                for fusion in ("fused", "unfused")]
+               + ["dp-q8-none", "dp-q8-ef"])
+
+
+@pytest.fixture(scope="module")
+def ring4():
+    r = _run_sub(RING4_SCRIPT)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    line = [ln for ln in r.stdout.splitlines() if ln.startswith("RING4 ")]
+    assert line, r.stdout[-2000:]
+    return json.loads(line[-1][len("RING4 "):])
+
+
+@pytest.mark.parametrize("case", RING4_CASES)
+def test_ring_of_four_matches_source_ordered_oracle(ring4, case):
+    """tp=4 gather / reduce-scatter (every rank's output) and dp=4 q8
+    reduce (EF over two steps, residuals included): bitwise the
+    oracle's."""
+    assert ring4[case] == "ok", ring4[case]
+
+
+@pytest.mark.parametrize("ring", ("tp-gather", "tp-scatter", "dp-reduce"))
+def test_ring_lowers_without_rank_indexed_uint8(ring4, ring):
+    """No stablehlo.scatter and no dynamic_slice / dynamic_update_slice on
+    a ui8 operand in the lowered q8 ring: received payloads stay in a
+    list by hop, ordered by source rank only after decode."""
+    assert ring4[f"lower-{ring}"] == "ok", ring4[f"lower-{ring}"]
 
 
 def _run_sub(script):
